@@ -1,4 +1,3 @@
-from .compat import shard_map
 from .compression import (
     compressed_psum,
     dequantize_int8,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
-
 
 def pipeline_apply(
     mesh: Mesh,
@@ -69,7 +67,7 @@ def pipeline_apply(
         jax.tree_util.tree_map(lambda _: P(axis), stage_params),
         P(),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return fn(stage_params, x_mb)

@@ -1,8 +1,9 @@
 """Hardware constants for the TPU v5e-class target (single source of truth).
 
 Used by the roofline analysis (benchmarks/roofline.py) and by the device /
-interconnect simulators (repro/sim).  The container is CPU-only: these model
-the *target*, they are never measured here.
+interconnect simulators (repro/sim).  These are the target's published
+figures, not measurements: the tests run on the CPU, and what the chip does
+is measured by runs on a TPU host (``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-* ``impl="pallas"`` runs the TPU kernel (``interpret=True`` automatically on
-  CPU, which executes the kernel body for correctness validation).
+* ``impl="pallas"`` runs the TPU kernel (``interpret=True`` on the CPU
+  backend, which executes the kernel body for correctness validation; any
+  other non-TPU backend raises).
 * ``impl="reference"`` runs the pure-jnp oracle (XLA-native; what the
   dry-runs lower so HLO stays representative).
 
@@ -28,7 +29,19 @@ from .ssm_scan import ssm_scan_fwd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compile the kernels for the TPU; interpret them only on the CPU.
+
+    Any other backend raises: a run that meant to use the chip and did not
+    find it must not fall back to the interpreter without a word."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for the TPU and interpret on the CPU; "
+        f"the default backend is {backend!r}"
+    )
 
 
 # ---------------------------------------------------------------------------

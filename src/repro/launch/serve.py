@@ -1,8 +1,30 @@
-"""Serving launcher: batched request serving with a reduced config on CPU.
+"""Serving launcher: batched request serving of a registry architecture.
 
-``python -m repro.launch.serve --arch qwen3-8b --requests 8 --smoke``
+``python -m repro.launch.serve --arch olmo-1b --requests 8``
+
+Serves the published config with random weights from a fixed seed;
+``--smoke`` serves the reduced config instead (CPU rehearsal).
 """
 import argparse
+from typing import List
+
+
+def make_requests(cfg, n: int, prompt_len: int, max_new: int) -> List:
+    """``n`` random prompts; odd requests sample at temperature 0.7, even
+    ones are greedy."""
+    import numpy as np
+
+    from ..serving import Request
+
+    rng = np.random.default_rng(0)
+    return [
+        Request(
+            prompt=rng.integers(0, cfg.vocab_size, size=prompt_len).astype(np.int32),
+            max_new_tokens=max_new,
+            temperature=0.7 if i % 2 else 0.0,
+        )
+        for i in range(n)
+    ]
 
 
 def main() -> None:
@@ -12,33 +34,28 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true", help="serve the reduced config")
     args = ap.parse_args()
 
     import jax
-    import numpy as np
 
     from ..configs import get_arch
     from ..models import init_params, model_pspecs
-    from ..serving import Request, ServingEngine
+    from ..serving import ServingEngine
+    from .compile_cache import use_compile_cache
 
-    cfg = get_arch(args.arch).config.reduced()
+    use_compile_cache()
+    cfg = get_arch(args.arch).config
+    if args.smoke:
+        cfg = cfg.reduced()
     params = init_params(jax.random.PRNGKey(0), model_pspecs(cfg))
     engine = ServingEngine(cfg, params, batch_size=args.batch,
                            max_seq=args.prompt_len + args.max_new)
-    rng = np.random.default_rng(0)
-    reqs = [
-        Request(
-            prompt=rng.integers(0, cfg.vocab_size, size=args.prompt_len).astype(np.int32),
-            max_new_tokens=args.max_new,
-            temperature=0.7 if i % 2 else 0.0,
-        )
-        for i in range(args.requests)
-    ]
-    engine.serve(reqs)
+    engine.serve(make_requests(cfg, args.requests, args.prompt_len, args.max_new))
     s = engine.stats
     print(
-        f"served {s.requests} requests in {s.waves} waves: "
+        f"served {s.requests} requests in {s.waves} waves on "
+        f"{jax.devices()[0].platform}: "
         f"{s.prefill_tokens} prefill + {s.decode_tokens} decode tokens, "
         f"{s.tokens_per_s:.0f} tok/s"
     )

@@ -1,21 +1,20 @@
 """Training launcher: ``python -m repro.launch.train --arch olmo-1b ...``
 
-Runs real training (synthetic data) on whatever devices exist.  With
-``--devices N`` it forces N host platform devices (must be first, before
-jax initializes) and builds a (data, model) mesh — the same code path the
-production mesh uses.
+Runs real training (synthetic data) on whatever devices exist; ``--mesh
+DxM`` builds a (data, model) mesh over them — the same code path the
+production mesh uses.  ``--devices N`` is for a CPU rehearsal only: it
+forces N host platform devices (set before jax initializes).
 """
 import argparse
-import dataclasses
 import os
-import sys
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="CPU rehearsal only: force N host platform devices")
     ap.add_argument("--mesh", default="", help="e.g. 4x2 -> (data=4, model=2)")
     ap.add_argument("--smoke", action="store_true", help="use the reduced config")
     ap.add_argument("--ckpt-every", type=int, default=0)
@@ -26,9 +25,10 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
-        )
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={args.devices}",
+        )))
 
     import jax
 
@@ -36,8 +36,10 @@ def main() -> None:
     from ..training.optimizer import AdamWConfig
     from ..training.train_step import TrainConfig
     from ..training.trainer import Trainer, TrainerConfig
+    from .compile_cache import use_compile_cache
     from .mesh import make_mesh
 
+    use_compile_cache()
     cfg = get_arch(args.arch).config
     if args.smoke:
         cfg = cfg.reduced()

@@ -42,7 +42,10 @@ def _init_leaf(key: jax.Array, p: PSpec) -> jax.Array:
     if p.init == "normal":
         return (p.scale * jax.random.normal(key, p.shape)).astype(p.dtype)
     if p.init in ("scaled", "lecun"):
-        fan_in = p.shape[0] if len(p.shape) >= 2 else max(np.prod(p.shape), 1)
+        # a stacked leaf (scanned layers, experts) holds one matrix per entry:
+        # its fan-in is that matrix's, not the stack's length
+        dims = [d for d, a in zip(p.shape, p.axes) if a not in ("layers", "expert")]
+        fan_in = dims[0] if len(dims) >= 2 else max(np.prod(dims), 1)
         std = p.scale / np.sqrt(fan_in)
         return (std * jax.random.normal(key, p.shape)).astype(p.dtype)
     raise ValueError(p.init)

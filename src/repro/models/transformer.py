@@ -167,7 +167,7 @@ def forward(
         x = embeds.astype(cfg.dtype)
         S = x.shape[1]
     else:
-        x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
         S = tokens.shape[1]
     if positions is None:
         positions = jnp.arange(S)
@@ -247,7 +247,7 @@ def prefill(
         x = embeds.astype(cfg.dtype)
         S = x.shape[1]
     else:
-        x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
         S = tokens.shape[1]
     max_seq = max_seq or S
     positions = jnp.arange(S)
@@ -365,7 +365,7 @@ def decode_step(
     if embeds is not None:
         x = embeds.astype(cfg.dtype)
     else:
-        x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
     x = constrain(x, "batch", None, None)
 
     new_cache: Dict[str, Any] = {"rest": []}

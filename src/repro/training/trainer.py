@@ -96,11 +96,14 @@ class Trainer:
     # -- state ------------------------------------------------------------------
 
     def init_state(self) -> Dict[str, Any]:
-        params = init_params(jax.random.PRNGKey(self.c.seed), model_pspecs(self.cfg))
-        state = init_train_state(self.cfg, params)
-        if self.state_shardings is not None:
-            state = jax.device_put(state, self.state_shardings)
-        return state
+        """Fresh state, created where it lives: on a mesh each device draws
+        only its own shard, so no device ever holds the whole model."""
+
+        def init():
+            params = init_params(jax.random.PRNGKey(self.c.seed), model_pspecs(self.cfg))
+            return init_train_state(self.cfg, params)
+
+        return jax.jit(init, out_shardings=self.state_shardings)()
 
     def restore_or_init(self) -> Dict[str, Any]:
         state = self.init_state()

@@ -11,10 +11,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
 
-def run_subprocess(script: str, devices: int = 0, timeout: int = 300) -> str:
+def run_subprocess(script: str, devices: int = 0, timeout: int = 300,
+                   env: dict = None) -> str:
     """Run a python snippet in a fresh interpreter (optionally with N forced
-    host devices) and return stdout; raises on nonzero exit."""
-    env = dict(os.environ)
+    host devices and extra environment variables) and return stdout; raises
+    on nonzero exit."""
+    env = {**os.environ, **(env or {})}
     env["PYTHONPATH"] = SRC
     if devices:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"

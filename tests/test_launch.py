@@ -1,0 +1,150 @@
+"""The launch path on CPU: meshes with Auto axes, sharded training against
+one device, the launchers, the compile-cache helper, kernel interpret mode,
+and ``chip_smoke.py`` as a rehearsal and as a refusal off the chip."""
+import json
+import os
+
+import jax
+import pytest
+
+from conftest import REPO
+
+CHIP_SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _run_chip_smoke(args):
+    """Snippet running chip_smoke.py in-process; prints its exit code."""
+    return f"""
+import runpy, sys
+sys.argv = [{CHIP_SMOKE!r}] + {list(args)!r}
+try:
+    runpy.run_path({CHIP_SMOKE!r}, run_name='__main__')
+except SystemExit as e:
+    print('EXIT', e.code)
+"""
+
+
+def test_mesh_training_matches_one_device(subproc):
+    """Two steps of reduced olmo-1b on a (data=2, model=2) mesh give the
+    losses of the same steps on one device (float32: same math, another
+    summation order)."""
+    out = subproc(
+        """
+import dataclasses
+from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
+from repro.training import AdamWConfig, TrainConfig
+from repro.training.trainer import Trainer, TrainerConfig
+cfg = dataclasses.replace(get_arch('olmo-1b').config.reduced(), dtype='float32')
+tc = TrainConfig(adamw=AdamWConfig(lr=1e-3, total_steps=3, warmup_steps=1))
+def losses(mesh):
+    tr = Trainer(cfg, tc, TrainerConfig(total_steps=3, log_every=0), mesh=mesh)
+    tr.run()
+    return [m['loss'] for m in tr.metrics_log]
+one = losses(None)
+mesh = make_mesh((2, 2), ('data', 'model'))
+from jax.sharding import AxisType
+assert mesh.axis_types == (AxisType.Auto, AxisType.Auto), mesh.axis_types
+sharded = losses(mesh)
+assert len(one) == 3 and one[2] < one[0] - 1e-2, one
+diff = max(abs(a - b) for a, b in zip(one, sharded))
+assert diff < 1e-4, (one, sharded)
+print('MESH_OK', diff)
+""",
+        devices=4,
+    )
+    assert "MESH_OK" in out
+
+
+def test_train_launcher_on_mesh(subproc, tmp_path):
+    """``launch/train.py --devices 4 --mesh 2x2`` trains, and --devices adds
+    its flag to XLA_FLAGS instead of replacing them."""
+    out = subproc(
+        """
+import os, sys
+sys.argv = ['train', '--arch', 'olmo-1b', '--smoke', '--steps', '2',
+            '--devices', '4', '--mesh', '2x2']
+from repro.launch.train import main
+main()
+import jax
+print('FLAGS', os.environ['XLA_FLAGS'])
+print('DEVICES', len(jax.devices()))
+""",
+        env={"XLA_FLAGS": "--xla_cpu_enable_fast_math=false",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
+    )
+    assert "done at step 2" in out
+    assert ("FLAGS --xla_cpu_enable_fast_math=false "
+            "--xla_force_host_platform_device_count=4") in out
+    assert "DEVICES 4" in out
+
+
+def test_serve_launcher_smoke(subproc, tmp_path):
+    out = subproc(
+        """
+import sys
+sys.argv = ['serve', '--arch', 'olmo-1b', '--smoke', '--requests', '4',
+            '--batch', '2', '--prompt-len', '16', '--max-new', '4']
+from repro.launch.serve import main
+main()
+""",
+        env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
+    )
+    assert "served 4 requests in 2 waves on cpu" in out
+
+
+@pytest.mark.parametrize("env_dir", [None, "/nonexistent/cache-from-env"])
+def test_compile_cache_dir(subproc, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the fixed
+    ``<checkout>/.jax_cache``."""
+    out = subproc(
+        f"""
+import os
+env_dir = {env_dir!r}
+if env_dir is None:
+    os.environ.pop('JAX_COMPILATION_CACHE_DIR', None)
+else:
+    os.environ['JAX_COMPILATION_CACHE_DIR'] = env_dir
+import jax
+from repro.launch.compile_cache import use_compile_cache
+print('USED', use_compile_cache())
+print('CONFIG', jax.config.jax_compilation_cache_dir)
+"""
+    )
+    want = env_dir or os.path.join(REPO, ".jax_cache")
+    assert f"USED {want}\n" in out
+    assert f"CONFIG {want}\n" in out
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
+def test_chip_smoke_refuses_cpu(subproc):
+    """Without --tiny the script stops at the device check on the CPU."""
+    out = subproc(_run_chip_smoke([]), env={"JAX_PLATFORMS": "cpu"})
+    assert "EXIT chip_smoke: JAX found no TPU" in out
+    assert '"ok": true' not in out
+    assert "compile cache" not in out      # stopped before anything else
+
+
+@pytest.mark.parametrize("four", [False, True], ids=["one-chip", "four-chips"])
+def test_chip_smoke_tiny_rehearsal(subproc, tmp_path, four):
+    args = ["--tiny"] + (["--four-chips"] if four else [])
+    out = subproc(
+        _run_chip_smoke(args),
+        devices=4 if four else 0,
+        env={"JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
+    )
+    assert "EXIT" not in out
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 4 if four else 1}}

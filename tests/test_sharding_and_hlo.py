@@ -50,7 +50,7 @@ def test_build_cell_shardings_match_abstract_shapes(subproc):
 import jax
 from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_cell
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = make_mesh((2, 2), ('data', 'model'))
 for shape in ('train_4k', 'prefill_32k', 'decode_32k'):
     cell = build_cell('olmo-1b', shape, mesh)
     flat_a = jax.tree_util.tree_leaves(cell.abstract_args)

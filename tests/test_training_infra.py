@@ -165,11 +165,11 @@ def test_compressed_psum_matches_exact(subproc):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.distributed.compat import shard_map
 from repro.distributed.compression import compressed_psum
-mesh = jax.make_mesh((4,), ('d',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ('d',))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 128))
-f = shard_map(lambda x: compressed_psum(x[0], 'd'), mesh=mesh,
+f = jax.shard_map(lambda x: compressed_psum(x[0], 'd'), mesh=mesh,
               in_specs=P('d'), out_specs=P(), check_vma=False)
 approx = f(x)
 exact = x.sum(0)
@@ -206,7 +206,8 @@ def test_pipeline_parallel_matches_sequential(subproc):
         """
 import jax, jax.numpy as jnp
 from repro.distributed.pipeline import pipeline_apply
-mesh = jax.make_mesh((4,), ('stage',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ('stage',))
 Ws = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 16)) * 0.3
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 2, 16))
 out = pipeline_apply(mesh, 'stage', lambda W, h: jnp.tanh(h @ W), Ws, x)
