@@ -56,8 +56,8 @@ def main() -> None:
     print(
         f"served {s.requests} requests in {s.waves} waves on "
         f"{jax.devices()[0].platform}: "
-        f"{s.prefill_tokens} prefill + {s.decode_tokens} decode tokens, "
-        f"{s.tokens_per_s:.0f} tok/s"
+        f"{s.prompt_tokens} prompt tokens (+{s.pad_tokens} padding) prefilled; "
+        f"{s.decode_steps} decode steps delivered {s.decode_rows_live} tokens"
     )
 
 

@@ -1,5 +1,7 @@
 """Core layers: norms, RoPE, attention (global/local, GQA, qk-norm, KV cache,
-int8 cache), MLP (SwiGLU/GeLU).  Pure functions over param pytrees.
+int8 cache), MLP (SwiGLU/GeLU).  Pure functions over param pytrees, each
+under a ``jax.named_scope`` (``norm``, ``attn`` with ``kv_write`` for the
+cache update, ``mlp``) that names its ops in the compiled program.
 
 Attention uses a *block-causal* reference implementation: a python loop over
 query blocks, each attending only the statically-known prefix (or local
@@ -51,6 +53,7 @@ def norm_pspec(cfg: ModelConfig, width: Optional[int] = None) -> Optional[PSpec]
     return PSpec((width or cfg.d_model,), ("embed_nr",), init="zeros")
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg: ModelConfig, x: jax.Array, scale: Optional[jax.Array]) -> jax.Array:
     if cfg.nonparametric_ln:
         return layernorm_nonparametric(x)
@@ -136,6 +139,7 @@ def _online_block_attn(
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
+@jax.named_scope("kv_write")
 def prefill_kv_cache(
     cfg: ModelConfig,
     k: jax.Array,                 # (B, K, S, hd) rope'd keys
@@ -168,6 +172,7 @@ def prefill_kv_cache(
     return entry
 
 
+@jax.named_scope("attn")
 def multihead_attention(
     cfg: ModelConfig,
     p: Params,
@@ -287,6 +292,7 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+@jax.named_scope("attn")
 def decode_attention(
     cfg: ModelConfig,
     p: Params,
@@ -316,13 +322,14 @@ def decode_attention(
     k_new = k.transpose(0, 2, 1, 3)        # (B, K, 1, hd)
     v_new = v.transpose(0, 2, 1, 3)
     if cfg.kv_cache_dtype == "int8":
-        kq, ks = _quantize_kv(k_new)
-        vq, vs = _quantize_kv(v_new)
-        cache = dict(cache)
-        cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, 0, slot, 0))
-        cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, 0, slot, 0))
-        cache["k_scale"] = jax.lax.dynamic_update_slice(cache["k_scale"], ks, (0, 0, slot, 0))
-        cache["v_scale"] = jax.lax.dynamic_update_slice(cache["v_scale"], vs, (0, 0, slot, 0))
+        with jax.named_scope("kv_write"):
+            kq, ks = _quantize_kv(k_new)
+            vq, vs = _quantize_kv(v_new)
+            cache = dict(cache)
+            cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, 0, slot, 0))
+            cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, 0, slot, 0))
+            cache["k_scale"] = jax.lax.dynamic_update_slice(cache["k_scale"], ks, (0, 0, slot, 0))
+            cache["v_scale"] = jax.lax.dynamic_update_slice(cache["v_scale"], vs, (0, 0, slot, 0))
         # dequantize to bf16 (int8 values are exact in bf16) and keep the
         # attention dots in bf16 with f32 accumulation — avoids an f32
         # materialization of the whole cache.  The Pallas decode kernel
@@ -330,9 +337,10 @@ def decode_attention(
         keys = cache["k"].astype(jnp.bfloat16) * cache["k_scale"].astype(jnp.bfloat16)
         vals = cache["v"].astype(jnp.bfloat16) * cache["v_scale"].astype(jnp.bfloat16)
     else:
-        cache = dict(cache)
-        cache["k"] = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, 0, slot, 0))
-        cache["v"] = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, 0, slot, 0))
+        with jax.named_scope("kv_write"):
+            cache = dict(cache)
+            cache["k"] = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, 0, slot, 0))
+            cache["v"] = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, 0, slot, 0))
         keys = cache["k"]
         vals = cache["v"]
 
@@ -376,6 +384,7 @@ def mlp_pspecs(cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     dt = x.dtype
     if cfg.mlp_act == "swiglu":

@@ -5,6 +5,10 @@ Layers are grouped by the config's block pattern period and stacked so a
 single ``lax.scan`` executes all full groups (HLO size O(pattern period),
 not O(depth)); remainder layers run unscanned.  Caches mirror the same
 grouping so decode scans carry them as scan xs/ys.
+
+Every path names its parts with ``jax.named_scope`` (``embed``, ``layers``,
+``head``; ``norm``, ``attn``, ``kv_write`` and ``mlp`` in ``layers.py``), so
+each compiled instruction's ``op_name`` metadata says which part it runs.
 """
 from __future__ import annotations
 
@@ -154,6 +158,25 @@ def _remat(cfg: ModelConfig, fn):
     return jax.checkpoint(fn, policy=policy)
 
 
+@jax.named_scope("embed")
+def _embed(cfg: ModelConfig, params: Params, tokens, embeds) -> jax.Array:
+    """Input activations (B, S, d) in the compute dtype."""
+    if embeds is not None:
+        return embeds.astype(cfg.dtype)
+    return params["embed"]["tok"][tokens].astype(cfg.dtype)
+
+
+@jax.named_scope("head")
+def _head(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    """Final norm and LM head: logits (B, S, V)."""
+    x = apply_norm(cfg, x, params.get("final_norm"))
+    head = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+    if cfg.logits_f32:
+        logits = logits.astype(jnp.float32)
+    return constrain(logits, "batch", None, "act_vocab")
+
+
 def forward(
     cfg: ModelConfig,
     params: Params,
@@ -163,37 +186,29 @@ def forward(
     train: bool = False,                      # capacity-drop MoE tokens (train only)
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits (B, S, V), moe_aux)."""
-    if embeds is not None:
-        x = embeds.astype(cfg.dtype)
-        S = x.shape[1]
-    else:
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-        S = tokens.shape[1]
+    x = _embed(cfg, params, tokens, embeds)
+    S = x.shape[1]
     if positions is None:
         positions = jnp.arange(S)
     x = constrain(x, "batch", None, None)
 
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
-        body = _remat(cfg, functools.partial(_group_body, cfg, positions=positions, train=train))
+    with jax.named_scope("layers"):
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+            body = _remat(cfg, functools.partial(_group_body, cfg, positions=positions, train=train))
 
-        def scan_fn(carry, gp):
-            return body(carry, gp), None
+            def scan_fn(carry, gp):
+                return body(carry, gp), None
 
-        (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), params["groups"])
-    # remainder layers (and the no-scan path) run unrolled:
-    rest_start = cfg.n_groups * cfg.pattern_period if (cfg.scan_layers and "groups" in params) else 0
-    for j, p_rest in enumerate(params["rest"]):
-        kind = cfg.block_kind(rest_start + j)
-        x, a = apply_block(cfg, kind, p_rest, x, positions, train=train)
-        aux = aux + a
+            (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), params["groups"])
+        # remainder layers (and the no-scan path) run unrolled:
+        rest_start = cfg.n_groups * cfg.pattern_period if (cfg.scan_layers and "groups" in params) else 0
+        for j, p_rest in enumerate(params["rest"]):
+            kind = cfg.block_kind(rest_start + j)
+            x, a = apply_block(cfg, kind, p_rest, x, positions, train=train)
+            aux = aux + a
 
-    x = apply_norm(cfg, x, params.get("final_norm"))
-    head = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-    if cfg.logits_f32:
-        logits = logits.astype(jnp.float32)
-    return constrain(logits, "batch", None, "act_vocab"), aux
+    return _head(cfg, params, x), aux
 
 
 # ---------------------------------------------------------------------------
@@ -243,43 +258,35 @@ def prefill(
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Full-sequence forward that also builds the decode cache.
     Returns (logits (B, S, V), cache sized for ``max_seq`` (default S))."""
-    if embeds is not None:
-        x = embeds.astype(cfg.dtype)
-        S = x.shape[1]
-    else:
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-        S = tokens.shape[1]
+    x = _embed(cfg, params, tokens, embeds)
+    S = x.shape[1]
     max_seq = max_seq or S
     positions = jnp.arange(S)
     x = constrain(x, "batch", None, None)
 
-    cache: Dict[str, Any] = {"rest": []}
-    if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+    with jax.named_scope("layers"):
+        cache: Dict[str, Any] = {"rest": []}
+        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
 
-        def scan_fn(carry, gp):
-            xc = carry
-            entries = {}
-            for i, kind in enumerate(cfg.block_pattern):
-                xc, c = apply_block_prefill(cfg, kind, gp[f"b{i}"], xc, positions, max_seq)
-                entries[f"b{i}"] = c
-            return xc, entries
+            def scan_fn(carry, gp):
+                xc = carry
+                entries = {}
+                for i, kind in enumerate(cfg.block_pattern):
+                    xc, c = apply_block_prefill(cfg, kind, gp[f"b{i}"], xc, positions, max_seq)
+                    entries[f"b{i}"] = c
+                return xc, entries
 
-        x, groups_cache = jax.lax.scan(scan_fn, x, params["groups"])
-        cache["groups"] = groups_cache
-        rest_start = cfg.n_groups * cfg.pattern_period
-    else:
-        rest_start = 0
-    for j, p_rest in enumerate(params["rest"]):
-        kind = cfg.block_kind(rest_start + j)
-        x, c = apply_block_prefill(cfg, kind, p_rest, x, positions, max_seq)
-        cache["rest"].append(c)
+            x, groups_cache = jax.lax.scan(scan_fn, x, params["groups"])
+            cache["groups"] = groups_cache
+            rest_start = cfg.n_groups * cfg.pattern_period
+        else:
+            rest_start = 0
+        for j, p_rest in enumerate(params["rest"]):
+            kind = cfg.block_kind(rest_start + j)
+            x, c = apply_block_prefill(cfg, kind, p_rest, x, positions, max_seq)
+            cache["rest"].append(c)
 
-    x = apply_norm(cfg, x, params.get("final_norm"))
-    head = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-    if cfg.logits_f32:
-        logits = logits.astype(jnp.float32)
-    return constrain(logits, "batch", None, "act_vocab"), cache
+    return _head(cfg, params, x), cache
 
 
 # ---------------------------------------------------------------------------
@@ -362,37 +369,30 @@ def decode_step(
     embeds: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One token for every sequence in the batch.  Returns (logits, cache)."""
-    if embeds is not None:
-        x = embeds.astype(cfg.dtype)
-    else:
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens, embeds)
     x = constrain(x, "batch", None, None)
 
-    new_cache: Dict[str, Any] = {"rest": []}
-    if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+    with jax.named_scope("layers"):
+        new_cache: Dict[str, Any] = {"rest": []}
+        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
 
-        def scan_fn(carry, xs):
-            xc = carry
-            gp, gc = xs
-            gc_new = {}
-            for i, kind in enumerate(cfg.block_pattern):
-                xc, c = apply_block_decode(cfg, kind, gp[f"b{i}"], xc, gc[f"b{i}"], pos)
-                gc_new[f"b{i}"] = c
-            return xc, gc_new
+            def scan_fn(carry, xs):
+                xc = carry
+                gp, gc = xs
+                gc_new = {}
+                for i, kind in enumerate(cfg.block_pattern):
+                    xc, c = apply_block_decode(cfg, kind, gp[f"b{i}"], xc, gc[f"b{i}"], pos)
+                    gc_new[f"b{i}"] = c
+                return xc, gc_new
 
-        x, groups_cache = jax.lax.scan(scan_fn, x, (params["groups"], cache["groups"]))
-        new_cache["groups"] = groups_cache
-        rest_start = cfg.n_groups * cfg.pattern_period
-    else:
-        rest_start = 0
-    for j, p_rest in enumerate(params["rest"]):
-        kind = cfg.block_kind(rest_start + j)
-        x, c = apply_block_decode(cfg, kind, p_rest, x, cache["rest"][j], pos)
-        new_cache["rest"].append(c)
+            x, groups_cache = jax.lax.scan(scan_fn, x, (params["groups"], cache["groups"]))
+            new_cache["groups"] = groups_cache
+            rest_start = cfg.n_groups * cfg.pattern_period
+        else:
+            rest_start = 0
+        for j, p_rest in enumerate(params["rest"]):
+            kind = cfg.block_kind(rest_start + j)
+            x, c = apply_block_decode(cfg, kind, p_rest, x, cache["rest"][j], pos)
+            new_cache["rest"].append(c)
 
-    x = apply_norm(cfg, x, params.get("final_norm"))
-    head = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-    if cfg.logits_f32:
-        logits = logits.astype(jnp.float32)
-    return constrain(logits, "batch", None, "act_vocab"), new_cache
+    return _head(cfg, params, x), new_cache

@@ -4,6 +4,13 @@ decode with greedy/temperature sampling, EOS + max-length termination.
 The engine serves fixed-size batch waves (static batching): requests are
 grouped into waves of ``batch_size``, each wave shares one KV cache and
 decodes in lockstep — the pattern the decode_32k dry-run cells lower.
+
+The engine annotates its own work for the profiler (``TraceAnnotation``,
+which costs a flag check while no profiler runs): ``engine.wave`` around a
+wave, and inside it ``engine.prefill`` and, per decode step,
+``engine.dispatch``, ``engine.token_sync`` and ``engine.bookkeeping``; each
+carries ``wave=<n>``, the per-step ones ``step=<k>`` too.  The decode
+program's sampling runs under the named scope ``sample``.
 """
 from __future__ import annotations
 
@@ -14,6 +21,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.config import ModelConfig
 from ..models.transformer import decode_step, init_cache, prefill
@@ -25,22 +33,21 @@ class Request:
     max_new_tokens: int = 32
     temperature: float = 0.0           # 0 = greedy
     eos_id: Optional[int] = None
-    # filled by the engine:
+    # filled by the engine, times by time.perf_counter() as each token
+    # reaches the host:
     output: Optional[np.ndarray] = None
-    latency_s: float = 0.0
+    first_token_s: Optional[float] = None
+    done_s: Optional[float] = None
 
 
 @dataclasses.dataclass
 class EngineStats:
     waves: int = 0
     requests: int = 0
-    prefill_tokens: int = 0
-    decode_tokens: int = 0
-    wall_s: float = 0.0
-
-    @property
-    def tokens_per_s(self) -> float:
-        return (self.prefill_tokens + self.decode_tokens) / max(self.wall_s, 1e-9)
+    prompt_tokens: int = 0       # requests' own prompt tokens prefilled
+    pad_tokens: int = 0          # prefill slots holding padding or no request
+    decode_steps: int = 0        # executions of the decode program
+    decode_rows_live: int = 0    # decode rows that delivered a token a request asked for
 
 
 class ServingEngine:
@@ -66,9 +73,10 @@ class ServingEngine:
         def _decode(p, toks, cache, pos, key, temps):
             logits, cache = decode_step(cfg, p, toks, cache, pos)
             logits = logits[:, 0, :]
-            greedy = jnp.argmax(logits, axis=-1)
-            sampled = jax.random.categorical(key, logits / jnp.maximum(temps, 1e-6)[:, None])
-            nxt = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1)
+                sampled = jax.random.categorical(key, logits / jnp.maximum(temps, 1e-6)[:, None])
+                nxt = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
             return nxt[:, None], cache
 
         self._decode = jax.jit(_decode, donate_argnums=(2,))
@@ -76,53 +84,63 @@ class ServingEngine:
     # -- wave execution -------------------------------------------------------
 
     def _run_wave(self, wave: List[Request]) -> None:
-        t0 = time.time()
+        n = self.stats.waves
+        with TraceAnnotation("engine.wave", wave=n):
+            self._wave(wave, n)
+        self.stats.waves += 1
+        self.stats.requests += len(wave)
+
+    def _wave(self, wave: List[Request], n: int) -> None:
         B = self.batch_size
-        S = max(len(r.prompt) for r in wave)
-        prompts = np.zeros((B, S), np.int32)
-        for i, r in enumerate(wave):
-            prompts[i, S - len(r.prompt):] = r.prompt  # left-pad into lockstep
-        logits, cache = self._prefill(self.params, jnp.asarray(prompts))
-        self.stats.prefill_tokens += B * S
+        with TraceAnnotation("engine.prefill", wave=n):
+            S = max(len(r.prompt) for r in wave)
+            prompts = np.zeros((B, S), np.int32)
+            for i, r in enumerate(wave):
+                prompts[i, S - len(r.prompt):] = r.prompt  # left-pad into lockstep
+            logits, cache = self._prefill(self.params, jnp.asarray(prompts))
+            temps = jnp.asarray(
+                [r.temperature for r in wave] + [0.0] * (B - len(wave)), jnp.float32
+            )
+            toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+            first = np.asarray(toks)
+            now = time.perf_counter()
+            outputs = [[] for _ in range(B)]
+            done = np.zeros(B, bool)
+            for i, r in enumerate(wave):
+                outputs[i].append(int(first[i, 0]))
+                r.first_token_s = now
+                if (r.eos_id is not None and first[i, 0] == r.eos_id) or r.max_new_tokens <= 1:
+                    done[i] = True
+                    r.done_s = now
+        prompt_tokens = sum(len(r.prompt) for r in wave)
+        self.stats.prompt_tokens += prompt_tokens
+        self.stats.pad_tokens += B * S - prompt_tokens
 
-        temps = jnp.asarray(
-            [r.temperature for r in wave] + [0.0] * (B - len(wave)), jnp.float32
-        )
         max_new = max(r.max_new_tokens for r in wave)
-        toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        outputs = [[] for _ in range(B)]
-        done = np.zeros(B, bool)
-        first = np.asarray(toks)
-        for i, r in enumerate(wave):
-            outputs[i].append(int(first[i, 0]))
-            if (r.eos_id is not None and first[i, 0] == r.eos_id) or r.max_new_tokens <= 1:
-                done[i] = True
-
         for step in range(1, max_new):
             if all(done[: len(wave)]):
                 break
-            self._key, sub = jax.random.split(self._key)
-            toks, cache = self._decode(
-                self.params, toks, cache, jnp.int32(S + step - 1), sub, temps
-            )
-            self.stats.decode_tokens += int(B)
-            host = np.asarray(toks)[:, 0]
-            for i, r in enumerate(wave):
-                if done[i]:
-                    continue
-                outputs[i].append(int(host[i]))
-                if (r.eos_id is not None and host[i] == r.eos_id) or len(outputs[i]) >= r.max_new_tokens:
-                    done[i] = True
-            if all(done[: len(wave)]):
-                break
+            with TraceAnnotation("engine.dispatch", wave=n, step=step):
+                self._key, sub = jax.random.split(self._key)
+                toks, cache = self._decode(
+                    self.params, toks, cache, jnp.int32(S + step - 1), sub, temps
+                )
+            with TraceAnnotation("engine.token_sync", wave=n, step=step):
+                host = np.asarray(toks)[:, 0]
+            now = time.perf_counter()
+            self.stats.decode_steps += 1
+            with TraceAnnotation("engine.bookkeeping", wave=n, step=step):
+                for i, r in enumerate(wave):
+                    if done[i]:
+                        continue
+                    outputs[i].append(int(host[i]))
+                    self.stats.decode_rows_live += 1
+                    if (r.eos_id is not None and host[i] == r.eos_id) or len(outputs[i]) >= r.max_new_tokens:
+                        done[i] = True
+                        r.done_s = now
 
-        dt = time.time() - t0
         for i, r in enumerate(wave):
             r.output = np.asarray(outputs[i][: r.max_new_tokens], np.int32)
-            r.latency_s = dt
-        self.stats.waves += 1
-        self.stats.requests += len(wave)
-        self.stats.wall_s += dt
 
     def serve(self, requests: List[Request]) -> List[Request]:
         for i in range(0, len(requests), self.batch_size):

@@ -116,6 +116,44 @@ print('CONFIG', jax.config.jax_compilation_cache_dir)
     assert f"CONFIG {want}\n" in out
 
 
+def test_compile_cache_keeps_each_programs_scopes(subproc, tmp_path):
+    """A program that differs from a cached one only in its named scopes is
+    compiled anew, so its profile names its own scopes; the same program
+    from another directory is found again."""
+    script = """
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from repro.launch.compile_cache import use_compile_cache
+use_compile_cache()
+jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **_: hits.append(name) if name == '/jax/compilation_cache/cache_hits' else None)
+def f(x):
+    if sys.argv[1] == 'scoped':
+        with jax.named_scope('mlp'):
+            return jnp.tanh(x @ x)
+    return jnp.tanh(x @ x)
+text = jax.jit(f).lower(np.ones((8, 8), np.float32)).compile().as_text()
+print('OP', [l.split('op_name=')[1].split()[0] for l in text.splitlines() if ' tanh(' in l][0])
+print('HITS', len(hits))
+"""
+    env = {"JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    outs = {}
+    for where, kind in (("a", "plain"), ("a", "scoped"), ("b", "scoped")):
+        d = tmp_path / where
+        d.mkdir(exist_ok=True)
+        (d / "f.py").write_text(script)
+        outs[where, kind] = subproc(
+            f"import sys, runpy; sys.argv = ['f.py', {kind!r}]; runpy.run_path({str(d / 'f.py')!r}, run_name='__main__')",
+            env=env)
+    assert 'OP "jit(f)/tanh"' in outs["a", "plain"]
+    assert 'OP "jit(f)/mlp/tanh"' in outs["a", "scoped"]
+    assert "HITS 0" in outs["a", "scoped"]
+    assert 'OP "jit(f)/mlp/tanh"' in outs["b", "scoped"]
+    assert "HITS 1" in outs["b", "scoped"]
+
+
 @pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
 def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
     from repro.kernels import ops

@@ -57,7 +57,21 @@ def test_batched_waves_and_stats(params):
     assert engine.stats.waves == 2
     assert engine.stats.requests == 6
     assert all(r.output is not None and len(r.output) == 4 for r in reqs)
-    assert engine.stats.tokens_per_s > 0
+    s = engine.stats
+    assert (s.prompt_tokens, s.pad_tokens, s.decode_steps, s.decode_rows_live) == (48, 16, 6, 18)
+    assert all(r.first_token_s <= r.done_s for r in reqs)
+
+    # one wave of known shape: prompts (4, 8, 8) left-padded to 8 in a batch
+    # of 4, outputs (2, 4, 3): three decode steps, six of their rows live
+    known = [Request(prompt=rng.integers(0, CFG.vocab_size, size=P).astype(np.int32),
+                     max_new_tokens=T) for P, T in ((4, 2), (8, 4), (8, 3))]
+    engine = ServingEngine(CFG, params, batch_size=4, max_seq=16)
+    engine.serve(known)
+    s = engine.stats
+    assert (s.waves, s.requests, s.prompt_tokens, s.pad_tokens) == (1, 3, 20, 12)
+    assert (s.decode_steps, s.decode_rows_live) == (3, 6)
+    assert [len(r.output) for r in known] == [2, 4, 3]
+    assert all(r.first_token_s <= r.done_s for r in known)
 
 
 def test_eos_stops_generation(params):
