@@ -68,12 +68,12 @@ def _entry_pspec(entry: Dict[str, Any], rules: Rules, stacked: bool) -> Dict[str
     for key, arr in entry.items():
         dims = arr.shape[1:] if stacked else arr.shape
         if key in ("k", "v", "k_scale", "v_scale"):
-            b, kheads, s, hd = dims
+            s, kheads, b, hd = dims            # layers.kv_cache_pspec's order
             out[key] = P(
                 *pre,
-                rules.mesh_axes_for("batch", b),
-                rules.mesh_axes_for("kv_heads", kheads),
                 rules.mesh_axes_for("cache_seq", s),
+                rules.mesh_axes_for("kv_heads", kheads),
+                rules.mesh_axes_for("batch", b),
                 None,
             )
         elif key == "conv":

@@ -147,15 +147,17 @@ def prefill_kv_cache(
     local: bool,
     max_seq: int,
 ) -> Dict[str, jax.Array]:
-    """Build a decode cache from prefill K/V (ring-buffer layout for local)."""
+    """Build a decode cache from prefill K/V, in the cache's (S, K, B, hd)
+    order (ring-buffer along S for local windows)."""
     B, K, S, hd = k.shape
     entry = init_kv_cache(cfg, B, local, max_seq)
-    W = entry["k"].shape[2]
+    W = entry["k"].shape[0]
+    k, v = k.transpose(2, 1, 0, 3), v.transpose(2, 1, 0, 3)   # (S, K, B, hd)
     if local:
         n = min(W, S)
-        kw, vw = k[:, :, S - n :], v[:, :, S - n :]
+        kw, vw = k[S - n :], v[S - n :]
         slots = (S - n + jnp.arange(n)) % W
-        write = lambda c, x: c.at[:, :, slots].set(x)
+        write = lambda c, x: c.at[slots].set(x)
     else:
         kw, vw = k, v
         write = lambda c, x: jax.lax.dynamic_update_slice(c, x, (0, 0, 0, 0))
@@ -263,19 +265,26 @@ def multihead_attention(
 
 
 def kv_cache_pspec(cfg: ModelConfig, batch: int, local: bool, max_seq: int) -> Dict[str, Any]:
-    """Abstract cache entry for one attention layer."""
+    """Abstract cache entry for one attention layer.
+
+    Keys and values are (S, K, B, hd): time, kv-head, batch, head-dim.  With
+    the layer axis in front, this is the order in which the TPU compiler
+    keeps the stacked cache that the decode scan carries, writes one token
+    into in place and reads each layer from where it lies; the
+    (B, K, S, hd) order makes it relayout the whole cache around the loop.
+    """
     K, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     S = min(cfg.window, max_seq) if local else max_seq
     cdt = {"int8": jnp.int8, "bfloat16": jnp.bfloat16, "float32": jnp.float32}[
         cfg.kv_cache_dtype
     ]
     entry = {
-        "k": jax.ShapeDtypeStruct((batch, K, S, hd), cdt),
-        "v": jax.ShapeDtypeStruct((batch, K, S, hd), cdt),
+        "k": jax.ShapeDtypeStruct((S, K, batch, hd), cdt),
+        "v": jax.ShapeDtypeStruct((S, K, batch, hd), cdt),
     }
     if cfg.kv_cache_dtype == "int8":
-        entry["k_scale"] = jax.ShapeDtypeStruct((batch, K, S, 1), jnp.float32)
-        entry["v_scale"] = jax.ShapeDtypeStruct((batch, K, S, 1), jnp.float32)
+        entry["k_scale"] = jax.ShapeDtypeStruct((S, K, batch, 1), jnp.float32)
+        entry["v_scale"] = jax.ShapeDtypeStruct((S, K, batch, 1), jnp.float32)
     return entry
 
 
@@ -297,15 +306,21 @@ def decode_attention(
     cfg: ModelConfig,
     p: Params,
     x: jax.Array,                  # (B, 1, d)
-    cache: Dict[str, jax.Array],
+    cache: Dict[str, jax.Array],   # (S, K, B, hd) leaves, or (G, S, K, B, hd) with ``layer``
     pos: jax.Array,                # scalar int32: number of tokens already cached
     local: bool,
+    layer: Optional[jax.Array] = None,   # scalar int32: this layer's index in a stacked cache
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One token of attention through the KV cache.  The step's K/V go into
+    slot ``pos`` (``pos % S`` for local windows) with one in-place
+    ``dynamic_update_slice`` per leaf; the attention then reads this layer's
+    keys and values where they lie.  With ``layer`` set the cache is the
+    scan's stacked carry and both index it at the layer."""
     B, _, d = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     g = H // K
     dt = x.dtype
-    S = cache["k"].shape[2]
+    S = cache["k"].shape[-4]
 
     q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dnh->bsnh", x, p["wk"].astype(dt))
@@ -319,49 +334,56 @@ def decode_attention(
 
     # write position: ring-buffer for local windows, linear otherwise
     slot = pos % S if local else pos
-    k_new = k.transpose(0, 2, 1, 3)        # (B, K, 1, hd)
-    v_new = v.transpose(0, 2, 1, 3)
+    lead = () if layer is None else (layer,)
+
+    def write(buf, new):           # new: (1, K, B, last)
+        new = new.astype(buf.dtype).reshape((1,) * len(lead) + new.shape)
+        return jax.lax.dynamic_update_slice(buf, new, (*lead, slot, 0, 0, 0))
+
+    def read(buf):
+        return buf if layer is None else jax.lax.dynamic_index_in_dim(buf, layer, 0, False)
+
+    k_new = k.transpose(1, 2, 0, 3)        # (1, K, B, hd)
+    v_new = v.transpose(1, 2, 0, 3)
+    cache = dict(cache)
     if cfg.kv_cache_dtype == "int8":
         with jax.named_scope("kv_write"):
             kq, ks = _quantize_kv(k_new)
             vq, vs = _quantize_kv(v_new)
-            cache = dict(cache)
-            cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, 0, slot, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, 0, slot, 0))
-            cache["k_scale"] = jax.lax.dynamic_update_slice(cache["k_scale"], ks, (0, 0, slot, 0))
-            cache["v_scale"] = jax.lax.dynamic_update_slice(cache["v_scale"], vs, (0, 0, slot, 0))
+            cache["k"] = write(cache["k"], kq)
+            cache["v"] = write(cache["v"], vq)
+            cache["k_scale"] = write(cache["k_scale"], ks)
+            cache["v_scale"] = write(cache["v_scale"], vs)
         # dequantize to bf16 (int8 values are exact in bf16) and keep the
         # attention dots in bf16 with f32 accumulation — avoids an f32
         # materialization of the whole cache.  The Pallas decode kernel
         # (kernels/decode_attention.py) streams int8 directly on TPU.
-        keys = cache["k"].astype(jnp.bfloat16) * cache["k_scale"].astype(jnp.bfloat16)
-        vals = cache["v"].astype(jnp.bfloat16) * cache["v_scale"].astype(jnp.bfloat16)
+        keys = read(cache["k"]).astype(jnp.bfloat16) * read(cache["k_scale"]).astype(jnp.bfloat16)
+        vals = read(cache["v"]).astype(jnp.bfloat16) * read(cache["v_scale"]).astype(jnp.bfloat16)
     else:
         with jax.named_scope("kv_write"):
-            cache = dict(cache)
-            cache["k"] = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, 0, slot, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, 0, slot, 0))
-        keys = cache["k"]
-        vals = cache["v"]
+            cache["k"] = write(cache["k"], k_new)
+            cache["v"] = write(cache["v"], v_new)
+        keys = read(cache["k"])
+        vals = read(cache["v"])
 
-    qh = q.reshape(B, 1, K, g, hd).transpose(0, 2, 3, 1, 4).astype(keys.dtype)  # (B,K,g,1,hd)
-    s = jnp.einsum(
-        "bkgqh,bkth->bkgqt", qh, keys, preferred_element_type=jnp.float32
-    )
+    # keys/vals: (S, K, B, hd); queries (B, K, g, hd)
+    qh = q.reshape(B, K, g, hd).astype(keys.dtype)
+    s = jnp.einsum("bkgh,tkbh->bkgt", qh, keys, preferred_element_type=jnp.float32)
 
     t_idx = jnp.arange(S)
     if local:
         # valid ring-buffer entries: the last min(pos+1, S) written slots
-        valid = t_idx[None, :] < jnp.minimum(pos + 1, S)
+        valid = t_idx < jnp.minimum(pos + 1, S)
     else:
-        valid = t_idx[None, :] <= pos
-    s = jnp.where(valid[None, None, None], s, -jnp.inf)
+        valid = t_idx <= pos
+    s = jnp.where(valid, s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum(
-        "bkgqt,bkth->bkgqh", w.astype(vals.dtype), vals,
+        "bkgt,tkbh->bkgh", w.astype(vals.dtype), vals,
         preferred_element_type=jnp.float32,
     )
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, 1, H, hd).astype(dt)
+    o = o.reshape(B, 1, H, hd).astype(dt)
     return jnp.einsum("bsnh,nhd->bsd", o, p["wo"].astype(dt)), cache
 
 

@@ -4,7 +4,9 @@ LM head; forward (train/prefill) and decode paths with caches.
 Layers are grouped by the config's block pattern period and stacked so a
 single ``lax.scan`` executes all full groups (HLO size O(pattern period),
 not O(depth)); remainder layers run unscanned.  Caches mirror the same
-grouping so decode scans carry them as scan xs/ys.
+grouping: prefill's scan emits the groups' caches as stacked ys, and the
+decode scan carries them whole beside the activations, so each layer writes
+its new token in place and reads its cache where it lies.
 
 Every path names its parts with ``jax.named_scope`` (``embed``, ``layers``,
 ``head``; ``norm``, ``attn``, ``kv_write`` and ``mlp`` in ``layers.py``), so
@@ -334,6 +336,23 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
     )
 
 
+def _layer_state(state: Dict[str, jax.Array], layer: Optional[jax.Array]) -> Dict[str, jax.Array]:
+    """A recurrent layer's own state: the entry at ``layer`` of a stacked one."""
+    if layer is None:
+        return state
+    return {k: jax.lax.dynamic_index_in_dim(a, layer, 0, False) for k, a in state.items()}
+
+
+def _put_layer_state(
+    state: Dict[str, jax.Array], new: Dict[str, jax.Array], layer: Optional[jax.Array]
+) -> Dict[str, jax.Array]:
+    """``state`` with ``new`` as its entry at ``layer`` (``new`` itself unstacked)."""
+    if layer is None:
+        return new
+    return {k: jax.lax.dynamic_update_index_in_dim(a, new[k].astype(a.dtype), layer, 0)
+            for k, a in state.items()}
+
+
 def apply_block_decode(
     cfg: ModelConfig,
     kind: str,
@@ -341,17 +360,21 @@ def apply_block_decode(
     x: jax.Array,              # (B, 1, d)
     cache: Dict[str, jax.Array],
     pos: jax.Array,            # scalar
+    layer: Optional[jax.Array] = None,   # index into a stacked (scan-carried) cache
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     h = apply_norm(cfg, x, p.get("norm1"))
     if kind in ("attn", "attn_local"):
-        y, cache = decode_attention(cfg, p["attn"], h, cache, pos, local=(kind == "attn_local"))
+        y, cache = decode_attention(
+            cfg, p["attn"], h, cache, pos, local=(kind == "attn_local"), layer=layer
+        )
         x = x + y
     elif kind == "rglru":
-        y, cache = rglru_decode(cfg, p["rglru"], h, cache)
+        y, new = rglru_decode(cfg, p["rglru"], h, _layer_state(cache, layer))
+        cache = _put_layer_state(cache, new, layer)
         x = x + y
     elif kind == "mamba":
-        y, cache = mamba_decode(cfg, p["mamba"], h, cache)
-        return x + y, cache
+        y, new = mamba_decode(cfg, p["mamba"], h, _layer_state(cache, layer))
+        return x + y, _put_layer_state(cache, new, layer)
     h2 = apply_norm(cfg, x, p.get("norm2"))
     if "moe" in p:
         y, _ = moe_block(cfg, p["moe"], h2)
@@ -368,7 +391,11 @@ def decode_step(
     pos: jax.Array,                    # scalar int32
     embeds: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One token for every sequence in the batch.  Returns (logits, cache)."""
+    """One token for every sequence in the batch.  Returns (logits, cache).
+
+    The layer scan carries the groups' stacked cache with the activations
+    and steps over (group params, group index): each layer updates its own
+    entry of the carried buffers, so a donated cache is updated in place."""
     x = _embed(cfg, params, tokens, embeds)
     x = constrain(x, "batch", None, None)
 
@@ -377,16 +404,18 @@ def decode_step(
         if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
 
             def scan_fn(carry, xs):
-                xc = carry
-                gp, gc = xs
-                gc_new = {}
+                xc, gc = carry
+                gp, layer = xs
+                gc = dict(gc)
                 for i, kind in enumerate(cfg.block_pattern):
-                    xc, c = apply_block_decode(cfg, kind, gp[f"b{i}"], xc, gc[f"b{i}"], pos)
-                    gc_new[f"b{i}"] = c
-                return xc, gc_new
+                    xc, gc[f"b{i}"] = apply_block_decode(
+                        cfg, kind, gp[f"b{i}"], xc, gc[f"b{i}"], pos, layer
+                    )
+                return (xc, gc), None
 
-            x, groups_cache = jax.lax.scan(scan_fn, x, (params["groups"], cache["groups"]))
-            new_cache["groups"] = groups_cache
+            (x, new_cache["groups"]), _ = jax.lax.scan(
+                scan_fn, (x, cache["groups"]), (params["groups"], jnp.arange(cfg.n_groups))
+            )
             rest_start = cfg.n_groups * cfg.pattern_period
         else:
             rest_start = 0

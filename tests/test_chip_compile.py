@@ -10,7 +10,9 @@ imported: only one process may load the TPU library at a time, and every
 test worker imports every test file.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,3 +93,82 @@ def test_olmo_pallas_prefill_compiles(one_chip, monkeypatch):
         lambda p, t: prefill(cfg, p, tokens=t, max_seq=1056)[0][:, -1], params, toks
     )
     assert "tpu_custom_call" in text
+
+
+def _computations(text):
+    """{name: [instruction lines]} of an optimized HLO module's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def test_olmo_decode_updates_cache_in_place(one_chip):
+    """The engine's decode program for olmo-1b at batch 16 and cache 1280,
+    its cache donated as the engine donates it: no instruction outside a
+    fusion makes a value with a layer's or the whole stack's K/V element
+    count, save the in-place ``dynamic-update-slice`` under ``kv_write``;
+    and every cache leaf is aliased from input to output, so each step
+    updates the same buffer."""
+    from repro.configs import get_arch
+    from repro.models import abstract_params, model_pspecs
+    from repro.models.transformer import cache_specs
+    from repro.serving import ServingEngine
+
+    cfg = get_arch("olmo-1b").config
+    B, S = 16, 1280
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    cache = jax.tree_util.tree_map(on_chip, cache_specs(cfg, B, S))
+    args = (jax.tree_util.tree_map(on_chip, abstract_params(model_pspecs(cfg))),
+            on_chip(jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+            cache,
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
+            on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32)),
+            on_chip(jax.ShapeDtypeStruct((B,), jnp.float32)))
+    engine = ServingEngine(cfg, None, batch_size=B, max_seq=S)
+    text = engine._decode.lower(*args).compile().as_text()
+
+    leaves = jax.tree_util.tree_leaves(cache)
+    want = (cfg.n_layers, S, cfg.n_kv_heads, B, cfg.resolved_head_dim)     # K and V
+    assert [a.shape for a in leaves] == [want] * 2
+    sizes = {math.prod(leaves[0].shape), math.prod(leaves[0].shape[1:])}
+    comps = _computations(text)
+    fused = {c for lines in comps.values() for line in lines if " fusion(" in line
+             for c in re.findall(r"calls=%([\w.\-]+)", line)}
+
+    def in_place_write(line):
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = line if called is None else next(
+            l for l in comps[called.group(1)] if l.lstrip().startswith("ROOT"))
+        return " dynamic-update-slice(" in root and "/kv_write/" in root
+
+    inst = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+    moved, writes = [], 0
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = inst.match(line)
+            if not m or math.prod(int(d) for d in m.group(2).split(",") if d) not in sizes:
+                continue
+            if m.group(3) in ("parameter", "get-tuple-element", "bitcast"):
+                continue
+            if m.group(3) in ("fusion", "dynamic-update-slice") and in_place_write(line):
+                writes += 1
+                continue
+            moved.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    assert not moved, moved
+    assert writes == 2                                   # K and V
+
+    cache_params = {int(n) for n in re.findall(
+        r"parameter\((\d+)\).*op_name=\"cache\[", text)}
+    aliased = {int(n) for n in re.findall(r"\{[\d,]*\}: \((\d+), \{\}", text)}
+    assert len(cache_params) == len(leaves)
+    assert cache_params <= aliased, (cache_params, aliased)

@@ -93,6 +93,38 @@ main()
     assert "served 4 requests in 2 waves on cpu" in out
 
 
+def test_cache_shardings_follow_the_cache_order(subproc):
+    """Each K/V leaf (and int8 scale) of the decode cache, stacked in the
+    layer scan or not, is sharded as (cache_seq, kv_heads, batch, None)
+    after its layer axis: the order of ``layers.kv_cache_pspec``."""
+    out = subproc(
+        """
+import dataclasses
+import jax
+from jax.sharding import PartitionSpec as P
+from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
+from repro.launch.specs import cache_shardings
+from repro.models.sharding import make_rules
+mesh = make_mesh((2, 2, 2), ('data', 'model', 'seq'))
+rules = make_rules(mesh, fsdp=False, shard_cache_seq='seq')
+base = dataclasses.replace(get_arch('gemma3-27b').config.reduced(), kv_cache_dtype='int8')
+n = 0
+for scan in (True, False):
+    cfg = dataclasses.replace(base, scan_layers=scan)
+    sh = cache_shardings(cfg, 4, 128, mesh, rules)
+    for path, s in jax.tree_util.tree_leaves_with_path(sh):
+        stacked = path[0].key == 'groups'
+        want = P(*([None] * stacked), 'seq', 'model', 'data', None)
+        assert s.spec == want, (jax.tree_util.keystr(path), s.spec)
+        n += 1
+print('SPECS_OK', n)
+""",
+        devices=8,
+    )
+    assert "SPECS_OK 72" in out
+
+
 @pytest.mark.parametrize("env_dir", [None, "/nonexistent/cache-from-env"])
 def test_compile_cache_dir(subproc, env_dir):
     """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the fixed
