@@ -1,6 +1,6 @@
-"""What every cell shares: finding its files by name, the device check, the
-compile cache and compile counter, per-layer readers over the trace, and
-the result line."""
+"""What every cell shares: finding its files by name (an architecture's
+module among them), the device check, the compile cache and compile
+counter, per-layer readers over the trace, and the result line."""
 from __future__ import annotations
 
 import dataclasses
@@ -14,7 +14,12 @@ import sys
 import tempfile
 from typing import Any, Dict, List, Optional
 
-ANNOTATIONS = ("serve_wave", "wait_arrivals")
+# host spans the trace is reduced with: the harness's own around its calls,
+# and the serving engine's inside ``serve_wave`` (``serving/engine.py``), so
+# that idle device time is split by what the engine was doing
+ENGINE_SPANS = ("engine.wave", "engine.prefill", "engine.dispatch", "engine.token_sync",
+                "engine.bookkeeping")
+ANNOTATIONS = ("serve_wave", "wait_arrivals") + ENGINE_SPANS
 
 
 class Refused(SystemExit):
@@ -44,6 +49,18 @@ def load_module(path: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def arch(c: Dict[str, Any]):
+    """The module of the architecture that the configuration ``c`` names
+    under ``reference`` (``bench/reference/<name>.py``): everything the
+    benchmark knows of it, its declaration ``COMPUTES`` (and ``FIELDS``),
+    its weight table ``shapes``, its reference ``logits`` and its least
+    work ``forward_flops`` and ``decode_least``."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "reference", c["reference"] + ".py")
+    if not os.path.isfile(path):
+        raise Refused(f"no architecture module {path} for {c['name']!r}")
+    return load_module(path)
 
 
 @dataclasses.dataclass

@@ -6,26 +6,42 @@ import dataclasses
 import time
 from typing import Any, Dict, List
 
-# configuration-file key -> ModelConfig field, for the keys both have
+# configuration-file key -> ModelConfig field, for the keys every
+# architecture's file may have; an architecture's module adds its own as
+# ``FIELDS``
 _FIELDS = {"d_model": "d_model", "n_layers": "n_layers", "n_heads": "n_heads",
            "n_kv_heads": "n_kv_heads", "head_dim": "head_dim", "d_ff": "d_ff",
            "vocab_size": "vocab_size", "rope_theta": "rope_theta", "mlp_act": "mlp_act",
            "weight_tying": "tie_embeddings", "param_dtype": "param_dtype",
            "compute_dtype": "dtype", "kv_cache_dtype": "kv_cache_dtype"}
+_MISSING = object()
 
 
 def model_config(c: Dict[str, Any]):
-    """The registry's config for ``c["arch"]`` with the file's values set,
-    checked to compute what the file says."""
+    """The registry's config for ``c["arch"]`` with the file's values set
+    (through ``_FIELDS`` and the architecture module's ``FIELDS``) and the
+    file's optional ``program`` section of further ``ModelConfig`` fields
+    set as they stand; checked to compute what the file says and what the
+    architecture's reference computes (its ``COMPUTES``)."""
     from repro.configs import get_arch
 
-    base = get_arch(c["arch"]).config
-    over = {f: c[k] for k, f in _FIELDS.items() if k in c}
-    cfg = dataclasses.replace(base, **over)
+    from bench import harness
+
+    arch = harness.arch(c)
+    fields = {**_FIELDS, **getattr(arch, "FIELDS", {})}
+    over = {f: c[k] for k, f in fields.items() if k in c}
+    program = c.get("program", {})
+    both = sorted(set(program) & set(over))
+    if both:
+        raise ValueError(f"{c['name']}: `program` sets {both}, which the file states under keys of its own")
+    cfg = dataclasses.replace(get_arch(c["arch"]).config, **over, **program)
     if cfg.nonparametric_ln != (c["norm"] == "layernorm_nonparametric"):
         raise ValueError(f"{c['name']}: the program's norm is not the file's {c['norm']!r}")
-    if cfg.block_pattern != ("attn",) or cfg.is_moe or cfg.qk_norm:
-        raise ValueError(f"{c['name']}: not a dense attention-only model")
+    wrong = [f"{f} {getattr(cfg, f, 'missing')!r} (the reference computes {sorted(map(repr, ok))})"
+             for f, ok in arch.COMPUTES.items() if getattr(cfg, f, _MISSING) not in ok]
+    if wrong:
+        raise ValueError(f"{c['name']}: the program computes what {c['reference']!r} does not: "
+                         + "; ".join(wrong))
     return cfg
 
 

@@ -1,32 +1,32 @@
 """Serving under open-loop arrivals, through ``ServingEngine.serve``.
 
-Set-up makes the weights on the device from the seed, builds the engine at
-the traffic's batch and cache size, and serves one warm-up wave per prompt
-bucket so that every program the window runs is compiled.  The window
-releases each request at its due time; whenever the engine is free it takes
-up to ``batch_size`` requests that are due, oldest first, and serves them
-as one wave.  Arrivals stop at ``--seconds``; the run then drains what is
-due.  ``serve_tokens_per_s`` is the output tokens delivered over the time
-from the window's opening to the last completion.
+Set-up makes the weights on the device from the seed (the weight table of
+the configuration's architecture module, ``harness.arch``), builds the
+engine at the traffic's batch and cache size, and serves one warm-up wave
+per prompt bucket so that every program the window runs is compiled.  The
+window releases each request at its due time; whenever the engine is free
+it takes up to ``batch_size`` requests that are due, oldest first, and
+serves them as one wave.  Arrivals stop at ``--seconds``; the run then
+drains what is due.  ``serve_tokens_per_s`` is the output tokens delivered
+over the time from the window's opening to the last completion.
 
 Correctness: once the window has closed, a sample of the finished requests
 drawn from the seed, with the longest greedy one in it, is scored by the
-float32 reference over each request's own prompt and its served tokens:
-``logit_gap`` is the widest amount by which a served token's reference
-logit lies below the reference's best at that position.  Greedy requests
-are scored at every served token, sampled ones at their first, which the
-engine takes greedily from the prefill.
+float32 reference of the architecture's module over each request's own
+prompt and its served tokens: ``logit_gap`` is the widest amount by which a
+served token's reference logit lies below the reference's best at that
+position.  Greedy requests are scored at every served token, sampled ones
+at their first, which the engine takes greedily from the prefill.
 """
 from __future__ import annotations
 
 import math
-import os
 from typing import Dict, List
 
 import numpy as np
 
 from bench import traffic_gen, weights
-from bench.harness import Outcome, Run, load_module
+from bench.harness import Outcome, Run, arch
 from bench.kinds import common
 
 
@@ -39,9 +39,11 @@ def run(run: Run) -> Outcome:
 
     c, t = run.c, run.t
     cfg = common.model_config(c)
+    ref = arch(c)
+    table = ref.shapes(c)
     B, max_seq = t["batch_size"], t["max_seq"]
 
-    params = jax.jit(lambda k: weights.make(c, k))(weights.seed_key(run.seed))
+    params = jax.jit(lambda k: weights.make(table, k))(weights.seed_key(run.seed))
     common.check_layout(cfg, params)
     engine = ServingEngine(cfg, params, batch_size=B, max_seq=max_seq, rng_seed=run.seed % 2**32)
     for P in t["prompt_buckets"]:
@@ -132,9 +134,8 @@ def run(run: Run) -> Outcome:
         run.note("no request finished: nothing to compare")
         return Outcome(metrics, n, len(failed), {"logit_gap": [None, run.cell.limits["logit_gap"]]},
                        record, mem, run.trace)
-    ref = load_module(os.path.join(run.root, "bench", "reference", c["reference"] + ".py"))
     t_ref = common.now()
-    w = jax.jit(lambda k: weights.make(c, k))(weights.seed_key(run.seed))
+    w = jax.jit(lambda k: weights.make(table, k))(weights.seed_key(run.seed))
     gaps = _gaps(ref, c, w, seqs, max_seq)
     checks = {"logit_gap": [max(g.max() for g, _ in gaps), run.cell.limits["logit_gap"]]}
     served = sum(len(g) for g, _ in gaps)

@@ -12,12 +12,13 @@ trace's one clock:
   HLO (an ``HloProto``, the stat ``Hlo Proto`` on the ``/host:metadata``
   plane), and that is read here for the scope of each instruction.
 
-The harness reduces the trace with its own annotations only and gives its
-readers no path to the file, so ``of(reading)`` finds the harness's trace
-file again (``bench_trace_*`` under the temporary directory, where the
-harness writes it): the one whose harness annotations are the reading's.  It
-reads the file once for all readers of a run.  A program without these
-marks (no ``engine.*`` span, no model scope) gives nothing to read.
+The harness reduces the trace with its annotations (the engine's spans
+among them) and gives its readers no path to the file, so ``of(reading)``
+finds the harness's trace file again (``bench_trace_*`` under the temporary
+directory, where the harness writes it): the one whose annotations are the
+reading's.  It reads the file once for all readers of a run.  A program
+without these marks (no ``engine.*`` span, no model scope) gives nothing to
+read.
 """
 from __future__ import annotations
 
@@ -33,8 +34,7 @@ from bench import harness
 from bench import trace_reduce as tr
 
 DECODE = "jit__decode"
-ENGINE_SPANS = ("engine.wave", "engine.prefill", "engine.dispatch", "engine.token_sync",
-                "engine.bookkeeping")
+ENGINE_SPANS = harness.ENGINE_SPANS
 STEP_SPANS = ("engine.dispatch", "engine.token_sync", "engine.bookkeeping")
 SCOPES = ("embed", "layers", "head", "norm", "attn", "kv_write", "mlp", "sample")
 UNSCOPED = "unscoped"      # an instruction under no model scope
@@ -219,8 +219,8 @@ _cache: List = []     # [the reading's trace, its ProgramTrace]
 def _find(r) -> Optional[Tuple[str, tr.Trace]]:
     pattern = os.path.join(tempfile.gettempdir(), "bench_trace_*", "**", "*.xplane.pb")
     for path in sorted(glob.glob(pattern, recursive=True), key=os.path.getmtime, reverse=True):
-        t = tr.load(path, harness.ANNOTATIONS + ENGINE_SPANS)
-        if [s for s in t.spans if s[2] in harness.ANNOTATIONS] == r.trace.spans:
+        t = tr.load(path, harness.ANNOTATIONS)
+        if t.spans == r.trace.spans:
             return path, t
     return None
 

@@ -1,24 +1,69 @@
-"""Plain reference of a dense decoder-only transformer, from its published
-description and the configuration file alone: pre-norm blocks of causal
-multi-head attention with rotary positions and a SwiGLU MLP, a final norm
-and an output head (the embedding, transposed, where the configuration ties
-them).  Straightforward ``jax.numpy`` in float32 with every matrix product
-at ``Precision.HIGHEST``; no cache, no batching of requests, no kernels.  It
-imports nothing of the program.
+"""A dense decoder-only transformer, as the benchmark knows it: the module
+that a configuration file names under ``reference`` (``harness.arch``).  It
+holds the four things the benchmark needs of an architecture, from its
+published description and the configuration file alone, and imports
+nothing of the program:
 
-``quant="fp8"`` gives the control: the same computation with both operands
-of every matrix product rounded to float8 e4m3, each under a scale of its
-own, as float8 serving recipes take them.
+- ``COMPUTES``: what the reference computes, in the program's
+  ``ModelConfig`` terms; ``kinds/common.model_config`` refuses a program
+  configuration that computes anything else (an architecture whose file
+  has keys of its own that set ``ModelConfig`` fields also maps them, as
+  ``FIELDS``; this one needs none);
+- ``shapes(c)``: the weight table ``{path: (shape, std)}`` in the program's
+  parameter layout (``models/transformer.py``: one stack of layers under
+  ``groups/b0``), which ``weights.make`` draws from the seed;
+- ``logits(c, w, tokens, quant=None)``: the plain reference, pre-norm blocks
+  of causal multi-head attention with rotary positions and a SwiGLU MLP, a
+  final norm and an output head (the embedding, transposed, where the
+  configuration ties them).  Straightforward ``jax.numpy`` in float32 with
+  every matrix product at ``Precision.HIGHEST``; no cache, no batching of
+  requests, no kernels.  ``quant="fp8"`` gives the control: the same
+  computation with both operands of every matrix product rounded to float8
+  e4m3, each under a scale of its own, as float8 serving recipes take them;
+- ``forward_flops`` and ``decode_least``: the least FLOPs and bytes of its
+  work (``bench/costs.py`` says how they are counted).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Iterable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.costs import BYTES
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# ModelConfig attribute -> the values this reference computes
+COMPUTES = {
+    "block_pattern": {("attn",)},        # global causal attention in every layer
+    "is_moe": {False},                   # one dense MLP per layer
+    "qk_norm": {False},
+    "nonparametric_ln": {True},          # LayerNorm without scale or bias
+    "mlp_act": {"swiglu"},
+    "tie_embeddings": {True, False},     # the head is whichever the weight table holds
+}
+
+
+def shapes(c: Dict) -> Dict:
+    """{path: (shape, std)} of every leaf."""
+    d, L, H, K, hd, f, V = (c[k] for k in
+                            ("d_model", "n_layers", "n_heads", "n_kv_heads", "head_dim", "d_ff", "vocab_size"))
+    out = {
+        # a tied embedding is also the head, so it is drawn at the head's scale
+        "embed/tok": ((V, d), d ** -0.5 if c["weight_tying"] else 1.0),
+        "groups/b0/attn/wq": ((L, d, H, hd), d ** -0.5),
+        "groups/b0/attn/wk": ((L, d, K, hd), d ** -0.5),
+        "groups/b0/attn/wv": ((L, d, K, hd), d ** -0.5),
+        "groups/b0/attn/wo": ((L, H, hd, d), (H * hd) ** -0.5),
+        "groups/b0/mlp/w_gate": ((L, d, f), d ** -0.5),
+        "groups/b0/mlp/w_up": ((L, d, f), d ** -0.5),
+        "groups/b0/mlp/w_down": ((L, f, d), f ** -0.5),
+    }
+    if not c["weight_tying"]:
+        out["lm_head"] = ((d, V), d ** -0.5)
+    return out
 
 
 def _fp8(x: jax.Array) -> jax.Array:
@@ -107,3 +152,59 @@ def logits(c: Dict, w: Dict, tokens: jax.Array, quant=None) -> jax.Array:
     with jax.default_matmul_precision("highest"):
         return mm("sd,dv->sv", hidden(c, w, tokens, quant), head(w), quant)
 
+
+
+# -- least FLOPs and bytes ----------------------------------------------------
+
+
+def layer_params(c: Dict) -> int:
+    """Matrix parameters of one block (attention and MLP)."""
+    d, H, K, hd, f = c["d_model"], c["n_heads"], c["n_kv_heads"], c["head_dim"], c["d_ff"]
+    attn = d * H * hd + 2 * d * K * hd + H * hd * d
+    mlp = (3 if c["mlp_act"] in ("swiglu", "geglu") else 2) * d * f
+    return attn + mlp
+
+
+def body_params(c: Dict) -> int:
+    return c["n_layers"] * layer_params(c)
+
+
+def head_params(c: Dict) -> int:
+    return c["d_model"] * c["vocab_size"]
+
+
+def attn_flops(c: Dict, contexts: Iterable[int]) -> float:
+    """QK and PV of one token per entry, each attending to that many keys."""
+    return 4.0 * c["n_layers"] * c["n_heads"] * c["head_dim"] * float(sum(contexts))
+
+
+def forward_flops(c: Dict, tokens: int, contexts_sum: float, head_tokens: int) -> float:
+    """A forward pass over ``tokens`` positions whose causal contexts sum to
+    ``contexts_sum``, with the head applied at ``head_tokens`` of them: the
+    projections and MLP of every layer, causal attention over the live
+    context only (QK and PV, each 2*head_dim per key), and the head.  The
+    embedding is a gather and costs no FLOPs."""
+    return (2.0 * body_params(c) * tokens
+            + 4.0 * c["n_layers"] * c["n_heads"] * c["head_dim"] * contexts_sum
+            + 2.0 * head_params(c) * head_tokens)
+
+
+def param_bytes(c: Dict) -> float:
+    """Bytes of the weights a decode step has to read as stored: every
+    matrix of the blocks and the head (a tied head is the embedding, read
+    whole; the rows the step gathers from it are counted there)."""
+    return float(body_params(c) + head_params(c)) * BYTES[c["param_dtype"]]
+
+
+def kv_bytes_per_token(c: Dict) -> float:
+    return 2.0 * c["n_layers"] * c["n_kv_heads"] * c["head_dim"] * BYTES[c.get("kv_cache_dtype", "bfloat16")]
+
+
+def decode_least(c: Dict, contexts: Iterable[int]) -> Dict[str, float]:
+    """Least FLOPs and HBM bytes of one decode step for the live rows, each
+    attending to the given context (its prompt and the tokens so far)."""
+    ctx = list(contexts)
+    rows = len(ctx)
+    flops = 2.0 * (body_params(c) + head_params(c)) * rows + attn_flops(c, ctx)
+    bytes_ = param_bytes(c) + kv_bytes_per_token(c) * float(sum(ctx))
+    return {"flops": flops, "bytes": bytes_}

@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     import numpy as np
     from jax.profiler import TraceAnnotation
 
-    from bench import program_trace, weights
+    from bench import harness, program_trace, weights
     from bench.kinds import common
     from repro.serving import Request, ServingEngine
 
@@ -51,7 +51,8 @@ def main(argv=None) -> int:
     c.update(c.pop("rehearsal"))
     c.pop("assumed")
     cfg = common.model_config(c)
-    params = jax.jit(lambda k: weights.make(c, k))(weights.seed_key(7))
+    table = harness.arch(c).shapes(c)
+    params = jax.jit(lambda k: weights.make(table, k))(weights.seed_key(7))
     engine = ServingEngine(cfg, params, batch_size=BATCH, max_seq=MAX_SEQ, rng_seed=7)
     rng = np.random.default_rng(7)
 

@@ -125,3 +125,32 @@ def test_readers_of_a_recorded_chip_trace_with_spans(as_harness):
         if n_ in pt.ENGINE_SPANS:
             assert any(a <= s and e <= b for a, b in waves), n_
     assert len([1 for _, _, n_ in p.trace.spans if n_ == "engine.dispatch"]) == n
+
+
+# every per-layer reader on the recorded trace, as the code before the
+# architecture modules and the engine spans among the harness's
+# annotations read it
+READ_BEFORE = {"engine.decode_row_use": 50.0, "engine.host_gap_share": 99.2593056390718,
+               "serve.wave_mfu": 0.0010086213128851758, "decode.step_ms": 0.021484923076925826,
+               "decode.hbm_roofline": 9.161163802700166, "engine.step_idle_ms": 2.6733233076923595,
+               "decode.plumbing_ms": 0.008480461538429615, "decode.attn_ms": 0.0056909999999877,
+               "decode.mlp_ms": 0.0009021538461508369}
+
+
+def test_every_reader_reads_as_before(as_harness):
+    name = "serve_spans_tiny.xplane.pb"
+    record, c = _meta(name)
+    t = as_harness(name)
+    assert t.window() == (0.042398423000000005, 0.085426877)
+    assert _read(OLD + NEW, t, record, c) == READ_BEFORE
+
+
+def test_idle_gaps_are_split_by_the_engines_spans(as_harness):
+    t = as_harness("serve_spans_tiny.xplane.pb")
+    lo, hi = t.window()
+    gaps = dict(tr.idle_by_host(t, lo, hi, n=100))
+    assert set(gaps) <= set(harness.ANNOTATIONS) | {"none"}
+    assert {"engine.dispatch", "engine.token_sync", "engine.prefill"} <= set(gaps)
+    assert "serve_wave" not in gaps          # every gap lies inside one of the engine's spans
+    dev = t.devices[0]
+    assert sum(gaps.values()) == pytest.approx((hi - lo) - tr.total(tr.busy(dev, lo, hi)), abs=1e-9)
