@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + their input shapes.
+"""Architecture registry: the assigned configs + their input shapes.
 
 Every arch is selectable via ``--arch <id>`` in the launchers.  Each entry
 records the exact published config (source in its module docstring), the
@@ -65,6 +65,7 @@ _ARCH_MODULES = [
     "chameleon_34b",
     "granite_moe_1b_a400m",
     "llama4_scout_17b_a16e",
+    "moonlight_16b_a3b",
 ]
 
 ARCHS: Dict[str, ArchSpec] = {}

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from . import ref
 from .decode_attention import decode_attention_fwd
 from .flash_attention import flash_attention_fwd
+from .mla_decode import mla_decode_fwd
 from .rglru_scan import rglru_scan_fwd
 from .rmsnorm import rmsnorm_fwd
 from .ssm_scan import ssm_scan_fwd
@@ -109,6 +110,21 @@ def decode_attention(
     return decode_attention_fwd(
         q, k, v, valid_len, scale=scale, block_s=block_s, interpret=_interpret()
     )
+
+
+def mla_decode(
+    q_lat: jax.Array,
+    q_pe: jax.Array,
+    c_kv: jax.Array,
+    k_pe: jax.Array,
+    layer: jax.Array,
+    pos: jax.Array,
+    scale: float,
+    impl: str = "pallas",
+) -> jax.Array:
+    if impl == "reference":
+        return ref.mla_decode_ref(q_lat, q_pe, c_kv, k_pe, layer, pos, scale)
+    return mla_decode_fwd(q_lat, q_pe, c_kv, k_pe, layer, pos, scale, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------

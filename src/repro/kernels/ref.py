@@ -56,6 +56,23 @@ def decode_attention_ref(
     return o.reshape(B, H, D).astype(q.dtype)
 
 
+def mla_decode_ref(
+    q_lat: jax.Array,        # (B, H, rank)
+    q_pe: jax.Array,         # (B, H, rope)
+    c_kv: jax.Array,         # (L, B, S, rank) stacked latent cache
+    k_pe: jax.Array,         # (L, B, rope, S) stacked rotary keys
+    layer: jax.Array,        # scalar int32
+    pos: jax.Array,          # scalar int32: last live slot
+    scale: float,
+) -> jax.Array:
+    c = jax.lax.dynamic_index_in_dim(c_kv, layer, 0, False).astype(jnp.float32)
+    r = jax.lax.dynamic_index_in_dim(k_pe, layer, 0, False).astype(jnp.float32)
+    s = (jnp.einsum("bhr,btr->bht", q_lat.astype(jnp.float32), c)
+         + jnp.einsum("bhe,bet->bht", q_pe.astype(jnp.float32), r)) * scale
+    s = jnp.where(jnp.arange(c.shape[1]) <= pos, s, -jnp.inf)
+    return jnp.einsum("bht,btr->bhr", jax.nn.softmax(s, axis=-1), c)
+
+
 def rglru_scan_ref(
     a: jax.Array,            # (B, L, W) decay in (0,1], f32
     x: jax.Array,            # (B, L, W) gated input, f32

@@ -62,7 +62,7 @@ def batch_shardings(
 
 
 def _entry_pspec(entry: Dict[str, Any], rules: Rules, stacked: bool) -> Dict[str, P]:
-    """PartitionSpec dict for one cache entry (kv / mamba / rglru)."""
+    """PartitionSpec dict for one cache entry (kv / latent / mamba / rglru)."""
     pre = (None,) if stacked else ()
     out: Dict[str, P] = {}
     for key, arr in entry.items():
@@ -76,6 +76,14 @@ def _entry_pspec(entry: Dict[str, Any], rules: Rules, stacked: bool) -> Dict[str
                 rules.mesh_axes_for("batch", b),
                 None,
             )
+        elif key == "c_kv":                    # mla.mla_cache_pspec's orders
+            b, s, _ = dims
+            out[key] = P(*pre, rules.mesh_axes_for("batch", b),
+                         rules.mesh_axes_for("cache_seq", s), None)
+        elif key == "k_pe":
+            b, _, s = dims
+            out[key] = P(*pre, rules.mesh_axes_for("batch", b), None,
+                         rules.mesh_axes_for("cache_seq", s))
         elif key == "conv":
             b, w, inner = dims
             out[key] = P(*pre, rules.mesh_axes_for("batch", b), None,
@@ -99,14 +107,15 @@ def cache_shardings(
     specs = cache_specs(cfg, batch, max_seq)
     ns = lambda spec: NamedSharding(mesh, spec)
     out: Dict[str, Any] = {"rest": []}
-    if "groups" in specs:
-        out["groups"] = {
-            name: jax.tree_util.tree_map(
-                ns, _entry_pspec(entry, rules, stacked=True),
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            for name, entry in specs["groups"].items()
-        }
+    for stack in ("lead", "groups"):
+        if stack in specs:
+            out[stack] = {
+                name: jax.tree_util.tree_map(
+                    ns, _entry_pspec(entry, rules, stacked=True),
+                    is_leaf=lambda x: isinstance(x, P),
+                )
+                for name, entry in specs[stack].items()
+            }
     for entry in specs["rest"]:
         out["rest"].append(
             jax.tree_util.tree_map(

@@ -54,10 +54,14 @@ def norm_pspec(cfg: ModelConfig, width: Optional[int] = None) -> Optional[PSpec]
 
 
 @jax.named_scope("norm")
-def apply_norm(cfg: ModelConfig, x: jax.Array, scale: Optional[jax.Array]) -> jax.Array:
-    if cfg.nonparametric_ln:
-        return layernorm_nonparametric(x)
-    return rmsnorm(x, scale)
+def apply_norm(cfg: ModelConfig, x: jax.Array, scale: Optional[jax.Array],
+               dtype: Optional[str] = None) -> jax.Array:
+    """The block's norm of the residual stream x, in ``dtype`` (default:
+    the compute dtype)."""
+    x = x.astype(jnp.float32) if dtype == "float32" else x
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    y = layernorm_nonparametric(x, **eps) if cfg.nonparametric_ln else rmsnorm(x, scale, **eps)
+    return y.astype(dtype or cfg.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +114,8 @@ def _online_block_attn(
 ) -> jax.Array:
     """Online-softmax over a static stack of KV blocks via lax.scan
     (or a python loop when ``unroll`` — cost-measurement mode)."""
-    B, K, g, Bq, hd = q.shape
+    B, K, g, Bq, _ = q.shape
+    hd = kv_blocks_v.shape[-1]
 
     def step(carry, kv):
         m, l, acc, idx = carry
@@ -137,6 +142,46 @@ def _online_block_attn(
         carry, _ = jax.lax.scan(step, carry, (kv_blocks_k, kv_blocks_v))
     m, l, acc, _ = carry
     return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def block_causal_attention(
+    cfg: ModelConfig,
+    q: jax.Array,          # (B, K, g, S, hd) scaled queries
+    k: jax.Array,          # (B, K, S, hd)
+    v: jax.Array,          # (B, K, S, hv)
+    block_q: int,
+    local: bool = False,
+) -> jax.Array:
+    """Causal (or local-window) attention of every query block over the
+    statically known prefix of key blocks: (B, K, g, S, hv) in float32."""
+    B, K, g, S, hd = q.shape
+    Bq = min(block_q, S)
+    n_q = max(S // Bq, 1)
+    window_blocks = max(1, -(-cfg.window // Bq)) if local else None
+
+    outs = []
+    for i in range(n_q):                     # python loop: static trip counts
+        qi = q[:, :, :, i * Bq : (i + 1) * Bq, :].astype(jnp.float32)
+        lo = 0 if not local else max(0, i - window_blocks)
+        hi = i + 1
+        kb = k[:, :, lo * Bq : hi * Bq, :]
+        vb = v[:, :, lo * Bq : hi * Bq, :]
+        nb = hi - lo
+        kb = kb.reshape(B, K, nb, Bq, hd).transpose(2, 0, 1, 3, 4)
+        vb = vb.reshape(B, K, nb, Bq, v.shape[-1]).transpose(2, 0, 1, 3, 4)
+
+        q_pos = i * Bq + jnp.arange(Bq)
+
+        def mask_fn(s, idx, lo=lo, q_pos=q_pos):
+            k_pos = (lo + idx) * Bq + jnp.arange(Bq)
+            m = q_pos[:, None] >= k_pos[None, :]
+            if local:
+                m &= q_pos[:, None] - k_pos[None, :] < cfg.window
+            return jnp.where(m[None, None, None], s, -jnp.inf)
+
+        o = _online_block_attn(qi, kb, vb, mask_fn, unroll=cfg.unroll_inner)
+        outs.append(o)                                       # (B,K,g,Bq,hv)
+    return jnp.concatenate(outs, axis=3)
 
 
 @jax.named_scope("kv_write")
@@ -225,35 +270,7 @@ def multihead_attention(
     q = q.reshape(B, S, K, g, hd).transpose(0, 2, 3, 1, 4)
     k = k.transpose(0, 2, 1, 3)
     v = v.transpose(0, 2, 1, 3)
-
-    Bq = min(block_q, S)
-    n_q = max(S // Bq, 1)
-    window_blocks = max(1, -(-cfg.window // Bq)) if local else None
-
-    outs = []
-    for i in range(n_q):                     # python loop: static trip counts
-        qi = q[:, :, :, i * Bq : (i + 1) * Bq, :].astype(jnp.float32)
-        lo = 0 if not local else max(0, i - window_blocks)
-        hi = i + 1
-        kb = k[:, :, lo * Bq : hi * Bq, :]
-        vb = v[:, :, lo * Bq : hi * Bq, :]
-        nb = hi - lo
-        kb = kb.reshape(B, K, nb, Bq, hd).transpose(2, 0, 1, 3, 4)
-        vb = vb.reshape(B, K, nb, Bq, hd).transpose(2, 0, 1, 3, 4)
-
-        q_pos = i * Bq + jnp.arange(Bq)
-
-        def mask_fn(s, idx, lo=lo, q_pos=q_pos):
-            k_pos = (lo + idx) * Bq + jnp.arange(Bq)
-            m = q_pos[:, None] >= k_pos[None, :]
-            if local:
-                m &= q_pos[:, None] - k_pos[None, :] < cfg.window
-            return jnp.where(m[None, None, None], s, -jnp.inf)
-
-        o = _online_block_attn(qi, kb, vb, mask_fn, unroll=cfg.unroll_inner)
-        outs.append(o)                                       # (B,K,g,Bq,hd)
-
-    out = jnp.concatenate(outs, axis=3)                      # (B,K,g,S,hd)
+    out = block_causal_attention(cfg, q, k, v, block_q, local)   # (B,K,g,S,hd)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(dt)
     y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(dt))
     if cache_max_seq is not None:

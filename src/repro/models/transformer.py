@@ -3,14 +3,18 @@ LM head; forward (train/prefill) and decode paths with caches.
 
 Layers are grouped by the config's block pattern period and stacked so a
 single ``lax.scan`` executes all full groups (HLO size O(pattern period),
-not O(depth)); remainder layers run unscanned.  Caches mirror the same
-grouping: prefill's scan emits the groups' caches as stacked ys, and the
-decode scan carries them whole beside the activations, so each layer writes
-its new token in place and reads its cache where it lies.
+not O(depth)); remainder layers run unscanned.  Leading layers that keep a
+dense MLP (``first_dense_layers``, before a MoE model's expert layers) are
+a stack of their own, ``lead``, scanned the same way before the groups.
+Caches mirror the same grouping: prefill's scan emits each stack's caches
+as stacked ys, and the decode scan carries them whole beside the
+activations, so each layer writes its new token in place and reads its
+cache where it lies.  Prefill applies the head at the last position only.
 
 Every path names its parts with ``jax.named_scope`` (``embed``, ``layers``,
-``head``; ``norm``, ``attn``, ``kv_write`` and ``mlp`` in ``layers.py``), so
-each compiled instruction's ``op_name`` metadata says which part it runs.
+``head``; ``norm``, ``attn``, ``kv_write`` and ``mlp`` in ``layers.py``,
+``mla.py`` and ``moe.py``), so each compiled instruction's ``op_name``
+metadata says which part it runs.
 """
 from __future__ import annotations
 
@@ -32,6 +36,7 @@ from .layers import (
     multihead_attention,
     norm_pspec,
 )
+from .mla import mla_attention, mla_cache_pspec, mla_decode, mla_pspecs
 from .moe import moe_block, moe_pspecs
 from .params import PSpec, is_pspec
 from .rglru import rglru_block, rglru_decode, rglru_pspecs, rglru_state_specs
@@ -46,13 +51,17 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def block_pspecs(cfg: ModelConfig, kind: str) -> Params:
+def block_pspecs(cfg: ModelConfig, kind: str, moe: bool = True) -> Params:
+    """One block's parameters; with ``moe`` false a MoE model's block keeps
+    the dense MLP (its leading layers)."""
     p: Params = {}
     n1 = norm_pspec(cfg)
     if n1 is not None:
         p["norm1"] = n1
     if kind in ("attn", "attn_local"):
         p["attn"] = attention_pspecs(cfg)
+    elif kind == "mla":
+        p["attn"] = mla_pspecs(cfg)
     elif kind == "rglru":
         p["rglru"] = rglru_pspecs(cfg)
     elif kind == "mamba":
@@ -61,7 +70,7 @@ def block_pspecs(cfg: ModelConfig, kind: str) -> Params:
     n2 = norm_pspec(cfg)
     if n2 is not None:
         p["norm2"] = n2
-    if cfg.is_moe:
+    if cfg.is_moe and moe:
         p["moe"] = moe_pspecs(cfg)
     else:
         p["mlp"] = mlp_pspecs(cfg)
@@ -96,15 +105,17 @@ def _with_param_dtype(tree: Params, cfg: ModelConfig) -> Params:
 
 
 def _model_pspecs_body(cfg: ModelConfig, p: Params) -> Params:
-    G, P_ = cfg.n_groups, cfg.pattern_period
+    G, P_, F = cfg.n_groups, cfg.pattern_period, cfg.first_dense_layers
+    if F:
+        p["lead"] = {"b0": _stack(block_pspecs(cfg, cfg.block_pattern[0], moe=False), F)}
     if cfg.scan_layers and G > 0:
         p["groups"] = {
             f"b{i}": _stack(block_pspecs(cfg, kind), G)
             for i, kind in enumerate(cfg.block_pattern)
         }
-        rest_kinds = [cfg.block_kind(G * P_ + j) for j in range(cfg.n_rest_layers)]
+        rest_kinds = [cfg.block_kind(F + G * P_ + j) for j in range(cfg.n_rest_layers)]
     else:
-        rest_kinds = [cfg.block_kind(i) for i in range(cfg.n_layers)]
+        rest_kinds = [cfg.block_kind(i) for i in range(F, cfg.n_layers)]
     p["rest"] = [block_pspecs(cfg, k) for k in rest_kinds]
     fn = norm_pspec(cfg)
     if fn is not None:
@@ -112,6 +123,22 @@ def _model_pspecs_body(cfg: ModelConfig, p: Params) -> Params:
     if not cfg.tie_embeddings:
         p["lm_head"] = PSpec((cfg.d_model, cfg.vocab_size), ("embed", "vocab"), init="lecun")
     return p
+
+
+def _stacks(cfg: ModelConfig, params: Params) -> List[Tuple[str, Tuple[str, ...]]]:
+    """The scanned stacks present, in layer order, each with its pattern."""
+    out = []
+    if "lead" in params:
+        out.append(("lead", cfg.block_pattern[:1]))
+    if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+        out.append(("groups", cfg.block_pattern))
+    return out
+
+
+def _rest_start(cfg: ModelConfig, params: Params) -> int:
+    """Index of the first unscanned layer."""
+    groups = cfg.scan_layers and "groups" in params
+    return cfg.first_dense_layers + (cfg.n_groups * cfg.pattern_period if groups else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -128,12 +155,14 @@ def apply_block(
     h = apply_norm(cfg, x, p.get("norm1"))
     if kind in ("attn", "attn_local"):
         x = x + multihead_attention(cfg, p["attn"], h, positions, local=(kind == "attn_local"))
+    elif kind == "mla":
+        x = x + mla_attention(cfg, p["attn"], h, positions)
     elif kind == "rglru":
         x = x + rglru_block(cfg, p["rglru"], h)
     elif kind == "mamba":
         return x + mamba_block(cfg, p["mamba"], h), aux
     x = constrain(x, "batch", None, None)
-    h2 = apply_norm(cfg, x, p.get("norm2"))
+    h2 = apply_norm(cfg, x, p.get("norm2"), "float32" if "moe" in p else None)
     if "moe" in p:
         y, aux = moe_block(cfg, p["moe"], h2, train=train)
     else:
@@ -142,9 +171,9 @@ def apply_block(
     return constrain(x, "batch", None, None), aux
 
 
-def _group_body(cfg: ModelConfig, carry, group_params, positions, train=False):
+def _group_body(cfg: ModelConfig, carry, group_params, positions, pattern, train=False):
     x, aux = carry
-    for i, kind in enumerate(cfg.block_pattern):
+    for i, kind in enumerate(pattern):
         x, a = apply_block(cfg, kind, group_params[f"b{i}"], x, positions, train=train)
         aux = aux + a
     return (x, aux)
@@ -196,15 +225,16 @@ def forward(
 
     with jax.named_scope("layers"):
         aux = jnp.zeros((), jnp.float32)
-        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
-            body = _remat(cfg, functools.partial(_group_body, cfg, positions=positions, train=train))
+        for name, pattern in _stacks(cfg, params):
+            body = _remat(cfg, functools.partial(_group_body, cfg, positions=positions,
+                                                 pattern=pattern, train=train))
 
-            def scan_fn(carry, gp):
+            def scan_fn(carry, gp, body=body):
                 return body(carry, gp), None
 
-            (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), params["groups"])
+            (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), params[name])
         # remainder layers (and the no-scan path) run unrolled:
-        rest_start = cfg.n_groups * cfg.pattern_period if (cfg.scan_layers and "groups" in params) else 0
+        rest_start = _rest_start(cfg, params)
         for j, p_rest in enumerate(params["rest"]):
             kind = cfg.block_kind(rest_start + j)
             x, a = apply_block(cfg, kind, p_rest, x, positions, train=train)
@@ -233,6 +263,9 @@ def apply_block_prefill(
             cache_max_seq=max_seq,
         )
         x = x + y
+    elif kind == "mla":
+        y, cache = mla_attention(cfg, p["attn"], h, positions, cache_max_seq=max_seq)
+        x = x + y
     elif kind == "rglru":
         from .rglru import rglru_block as _rg
 
@@ -243,7 +276,7 @@ def apply_block_prefill(
 
         y, cache = _mb(cfg, p["mamba"], h, return_state=True)
         return x + y, cache
-    h2 = apply_norm(cfg, x, p.get("norm2"))
+    h2 = apply_norm(cfg, x, p.get("norm2"), "float32" if "moe" in p else None)
     if "moe" in p:
         y, _ = moe_block(cfg, p["moe"], h2)
     else:
@@ -259,7 +292,8 @@ def prefill(
     max_seq: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Full-sequence forward that also builds the decode cache.
-    Returns (logits (B, S, V), cache sized for ``max_seq`` (default S))."""
+    Returns (logits (B, 1, V) at the last position, cache sized for
+    ``max_seq`` (default S))."""
     x = _embed(cfg, params, tokens, embeds)
     S = x.shape[1]
     max_seq = max_seq or S
@@ -268,27 +302,23 @@ def prefill(
 
     with jax.named_scope("layers"):
         cache: Dict[str, Any] = {"rest": []}
-        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+        for name, pattern in _stacks(cfg, params):
 
-            def scan_fn(carry, gp):
-                xc = carry
+            def scan_fn(xc, gp, pattern=pattern):
                 entries = {}
-                for i, kind in enumerate(cfg.block_pattern):
+                for i, kind in enumerate(pattern):
                     xc, c = apply_block_prefill(cfg, kind, gp[f"b{i}"], xc, positions, max_seq)
                     entries[f"b{i}"] = c
                 return xc, entries
 
-            x, groups_cache = jax.lax.scan(scan_fn, x, params["groups"])
-            cache["groups"] = groups_cache
-            rest_start = cfg.n_groups * cfg.pattern_period
-        else:
-            rest_start = 0
+            x, cache[name] = jax.lax.scan(scan_fn, x, params[name])
+        rest_start = _rest_start(cfg, params)
         for j, p_rest in enumerate(params["rest"]):
             kind = cfg.block_kind(rest_start + j)
             x, c = apply_block_prefill(cfg, kind, p_rest, x, positions, max_seq)
             cache["rest"].append(c)
 
-    return _head(cfg, params, x), cache
+    return _head(cfg, params, x[:, -1:]), cache
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +331,8 @@ def _cache_entry_spec(cfg: ModelConfig, kind: str, batch: int, max_seq: int):
         return kv_cache_pspec(cfg, batch, local=False, max_seq=max_seq)
     if kind == "attn_local":
         return kv_cache_pspec(cfg, batch, local=True, max_seq=max_seq)
+    if kind == "mla":
+        return mla_cache_pspec(cfg, batch, max_seq)
     if kind == "rglru":
         return rglru_state_specs(cfg, batch)
     if kind == "mamba":
@@ -311,21 +343,23 @@ def _cache_entry_spec(cfg: ModelConfig, kind: str, batch: int, max_seq: int):
 def cache_specs(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
     """Abstract cache pytree mirroring the grouped layer structure."""
     out: Dict[str, Any] = {}
-    G = cfg.n_groups
+    G, F = cfg.n_groups, cfg.first_dense_layers
 
-    def stack_specs(tree):
+    def stack_specs(tree, n):
         return jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct((G,) + s.shape, s.dtype), tree
+            lambda s: jax.ShapeDtypeStruct((n,) + s.shape, s.dtype), tree
         )
 
+    if F:
+        out["lead"] = {"b0": stack_specs(_cache_entry_spec(cfg, cfg.block_pattern[0], batch, max_seq), F)}
     if cfg.scan_layers and G > 0:
         out["groups"] = {
-            f"b{i}": stack_specs(_cache_entry_spec(cfg, kind, batch, max_seq))
+            f"b{i}": stack_specs(_cache_entry_spec(cfg, kind, batch, max_seq), G)
             for i, kind in enumerate(cfg.block_pattern)
         }
-        rest_kinds = [cfg.block_kind(G * cfg.pattern_period + j) for j in range(cfg.n_rest_layers)]
+        rest_kinds = [cfg.block_kind(F + G * cfg.pattern_period + j) for j in range(cfg.n_rest_layers)]
     else:
-        rest_kinds = [cfg.block_kind(i) for i in range(cfg.n_layers)]
+        rest_kinds = [cfg.block_kind(i) for i in range(F, cfg.n_layers)]
     out["rest"] = [_cache_entry_spec(cfg, k, batch, max_seq) for k in rest_kinds]
     return out
 
@@ -368,6 +402,9 @@ def apply_block_decode(
             cfg, p["attn"], h, cache, pos, local=(kind == "attn_local"), layer=layer
         )
         x = x + y
+    elif kind == "mla":
+        y, cache = mla_decode(cfg, p["attn"], h, cache, pos, layer=layer)
+        x = x + y
     elif kind == "rglru":
         y, new = rglru_decode(cfg, p["rglru"], h, _layer_state(cache, layer))
         cache = _put_layer_state(cache, new, layer)
@@ -375,7 +412,7 @@ def apply_block_decode(
     elif kind == "mamba":
         y, new = mamba_decode(cfg, p["mamba"], h, _layer_state(cache, layer))
         return x + y, _put_layer_state(cache, new, layer)
-    h2 = apply_norm(cfg, x, p.get("norm2"))
+    h2 = apply_norm(cfg, x, p.get("norm2"), "float32" if "moe" in p else None)
     if "moe" in p:
         y, _ = moe_block(cfg, p["moe"], h2)
     else:
@@ -401,24 +438,23 @@ def decode_step(
 
     with jax.named_scope("layers"):
         new_cache: Dict[str, Any] = {"rest": []}
-        if cfg.scan_layers and cfg.n_groups > 0 and "groups" in params:
+        for name, pattern in _stacks(cfg, params):
 
-            def scan_fn(carry, xs):
+            def scan_fn(carry, xs, pattern=pattern):
                 xc, gc = carry
                 gp, layer = xs
                 gc = dict(gc)
-                for i, kind in enumerate(cfg.block_pattern):
+                for i, kind in enumerate(pattern):
                     xc, gc[f"b{i}"] = apply_block_decode(
                         cfg, kind, gp[f"b{i}"], xc, gc[f"b{i}"], pos, layer
                     )
                 return (xc, gc), None
 
-            (x, new_cache["groups"]), _ = jax.lax.scan(
-                scan_fn, (x, cache["groups"]), (params["groups"], jnp.arange(cfg.n_groups))
+            n = jax.tree_util.tree_leaves(params[name])[0].shape[0]
+            (x, new_cache[name]), _ = jax.lax.scan(
+                scan_fn, (x, cache[name]), (params[name], jnp.arange(n))
             )
-            rest_start = cfg.n_groups * cfg.pattern_period
-        else:
-            rest_start = 0
+        rest_start = _rest_start(cfg, params)
         for j, p_rest in enumerate(params["rest"]):
             kind = cfg.block_kind(rest_start + j)
             x, c = apply_block_decode(cfg, kind, p_rest, x, cache["rest"][j], pos)

@@ -59,6 +59,23 @@ def test_decode_attention_sweep(S, D, valid, dtype):
                                np.asarray(exp, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,pos,layer", [(128, 127, 0), (256, 100, 2), (128, 0, 1)])
+def test_mla_decode_sweep(S, pos, layer, dtype):
+    B, H, L, rank, rope = 2, 4, 3, 128, 64
+    ks = jax.random.split(KEY, 4)
+    q_lat = jax.random.normal(ks[0], (B, H, rank), dtype)
+    q_pe = jax.random.normal(ks[1], (B, H, rope), dtype)
+    c_kv = jax.random.normal(ks[2], (L, B, S, rank), dtype)
+    k_pe = jax.random.normal(ks[3], (L, B, rope, S), dtype)
+    args = (q_lat, q_pe, c_kv, k_pe, jnp.int32(layer), jnp.int32(pos), 0.1)
+    out = ops.mla_decode(*args, impl="pallas")
+    exp = ref.mla_decode_ref(*args)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), atol=tol, rtol=tol)
+
+
 @pytest.mark.parametrize("L,W,bw", [(32, 256, 128), (64, 512, 512), (17, 256, 256)])
 def test_rglru_scan_sweep(L, W, bw):
     B = 2

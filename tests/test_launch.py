@@ -125,6 +125,35 @@ print('SPECS_OK', n)
     assert "SPECS_OK 72" in out
 
 
+def test_latent_cache_shardings_follow_the_cache_order(subproc):
+    """Each latent-cache leaf of an MLA model (the leading dense layer's
+    stack and the expert layers' stack) is sharded after its layer axis in
+    the order of ``mla.mla_cache_pspec``: ``c_kv`` (batch, cache_seq, None),
+    ``k_pe`` (batch, None, cache_seq)."""
+    out = subproc(
+        """
+import jax
+from jax.sharding import PartitionSpec as P
+from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
+from repro.launch.specs import cache_shardings
+from repro.models.sharding import make_rules
+mesh = make_mesh((2, 2, 2), ('data', 'model', 'seq'))
+rules = make_rules(mesh, fsdp=False, shard_cache_seq='seq')
+cfg = get_arch('moonlight-16b-a3b').config.reduced(n_layers=3)
+sh = cache_shardings(cfg, 4, 128, mesh, rules)
+stacks = []
+want = {'c_kv': P(None, 'data', 'seq', None), 'k_pe': P(None, 'data', None, 'seq')}
+for path, s in jax.tree_util.tree_leaves_with_path(sh):
+    assert s.spec == want[path[-1].key], (jax.tree_util.keystr(path), s.spec)
+    stacks.append(path[0].key)
+print('SPECS_OK', sorted(stacks))
+""",
+        devices=8,
+    )
+    assert "SPECS_OK ['groups', 'groups', 'lead', 'lead']" in out
+
+
 @pytest.mark.parametrize("env_dir", [None, "/nonexistent/cache-from-env"])
 def test_compile_cache_dir(subproc, env_dir):
     """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the fixed

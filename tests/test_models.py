@@ -60,7 +60,8 @@ def test_arch_smoke_forward_and_train_step(arch, rng):
 
 @slow
 @pytest.mark.parametrize("arch", ["qwen3-8b", "gemma3-27b", "recurrentgemma-2b",
-                                  "falcon-mamba-7b", "granite-moe-1b-a400m"])
+                                  "falcon-mamba-7b", "granite-moe-1b-a400m",
+                                  "moonlight-16b-a3b"])
 def test_arch_decode_matches_forward(arch, rng):
     cfg = dataclasses.replace(
         get_arch(arch).config.reduced(),
@@ -79,7 +80,8 @@ def test_arch_decode_matches_forward(arch, rng):
 
 
 @slow
-@pytest.mark.parametrize("arch", ["gemma3-27b", "recurrentgemma-2b", "falcon-mamba-7b"])
+@pytest.mark.parametrize("arch", ["gemma3-27b", "recurrentgemma-2b", "falcon-mamba-7b",
+                                  "moonlight-16b-a3b"])
 def test_arch_prefill_then_decode(arch, rng):
     cfg = dataclasses.replace(
         get_arch(arch).config.reduced(),
@@ -90,7 +92,7 @@ def test_arch_prefill_then_decode(arch, rng):
     toks = jax.random.randint(rng, (B, S), 0, cfg.vocab_size)
     full, _ = jax.jit(lambda p, t: forward(cfg, p, t))(params, toks)
     lg_pf, cache = jax.jit(lambda p, t: prefill(cfg, p, t, max_seq=S))(params, toks[:, :P])
-    assert float(jnp.max(jnp.abs(lg_pf - full[:, :P]))) < 5e-3
+    assert float(jnp.max(jnp.abs(lg_pf - full[:, P - 1 : P]))) < 5e-3    # the head at the last position
     dec = jax.jit(lambda p, t, c, pos: decode_step(cfg, p, t, c, pos))
     for i in range(P, S):
         lg, cache = dec(params, toks[:, i : i + 1], cache, jnp.int32(i))
@@ -128,6 +130,19 @@ def test_param_count_estimates_match_declared_tree():
         analytic = cfg.n_params
         ratio = declared / analytic
         assert 0.9 < ratio < 1.12, f"{arch}: declared={declared:.3e} analytic={analytic:.3e}"
+
+
+def test_prefill_head_is_forwards_last_position(rng):
+    """olmo-1b's prefill applies the head at the last position only: its
+    logits (B, 1, V) are forward's logits there."""
+    cfg = dataclasses.replace(get_arch("olmo-1b").config.reduced(), dtype="float32",
+                              kv_cache_dtype="float32")
+    params = jax.jit(lambda k: init_params(k, model_pspecs(cfg)))(rng)
+    toks = jax.random.randint(rng, (2, 24), 0, cfg.vocab_size)
+    full, _ = jax.jit(lambda p, t: forward(cfg, p, t))(params, toks)
+    last, _ = jax.jit(lambda p, t: prefill(cfg, p, t, max_seq=32))(params, toks)
+    assert last.shape == (2, 1, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(full[:, -1:]), rtol=0, atol=1e-5)
 
 
 @pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unscanned"])
