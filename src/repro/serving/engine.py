@@ -11,6 +11,15 @@ wave, and inside it ``engine.prefill`` and, per decode step,
 ``engine.dispatch``, ``engine.token_sync`` and ``engine.bookkeeping``; each
 carries ``wave=<n>``, the per-step ones ``step=<k>`` too.  The decode
 program's sampling runs under the named scope ``sample``.
+
+The decode program carries the sampling key and the position itself, so a
+step needs nothing from the host but the previous step's tokens, which it
+reads on the device.  The engine therefore dispatches step k+1 before it
+fetches step k's tokens whenever some live row is certain to need step
+k+1 (a row with no ``eos_id`` ends at a length known beforehand), and the
+device runs that step while the host reads and books step k.  Where every
+live row could end on the token not yet fetched, it fetches first: no step
+runs that a fetch-first loop would not run.
 """
 from __future__ import annotations
 
@@ -48,6 +57,7 @@ class EngineStats:
     pad_tokens: int = 0          # prefill slots holding padding or no request
     decode_steps: int = 0        # executions of the decode program
     decode_rows_live: int = 0    # decode rows that delivered a token a request asked for
+    decode_ahead: int = 0        # decode executions dispatched before the tokens they follow reached the host
 
 
 class ServingEngine:
@@ -74,10 +84,12 @@ class ServingEngine:
             logits, cache = decode_step(cfg, p, toks, cache, pos)
             logits = logits[:, 0, :]
             with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
                 greedy = jnp.argmax(logits, axis=-1)
-                sampled = jax.random.categorical(key, logits / jnp.maximum(temps, 1e-6)[:, None])
+                sampled = jax.random.categorical(sub, logits / jnp.maximum(temps, 1e-6)[:, None])
                 nxt = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
-            return nxt[:, None], cache
+                pos = pos + 1
+            return nxt[:, None], cache, pos, key
 
         self._decode = jax.jit(_decode, donate_argnums=(2,))
 
@@ -92,6 +104,40 @@ class ServingEngine:
 
     def _wave(self, wave: List[Request], n: int) -> None:
         B = self.batch_size
+        # the last step some live row is certain to need: a row with no
+        # eos_id ends at its length, whatever it samples
+        certain = max((r.max_new_tokens - 1 for r in wave if r.eos_id is None), default=0)
+        outputs = [[] for _ in range(B)]
+        done = np.zeros(B, bool)
+
+        def dispatch(step, toks, cache, pos):
+            with TraceAnnotation("engine.dispatch", wave=n, step=step):
+                toks, cache, pos, self._key = self._decode(
+                    self.params, toks, cache, pos, self._key, temps
+                )
+            return toks, cache, pos
+
+        def dispatch_ahead(step, toks, cache, pos):
+            """Step ``step``, dispatched before the tokens it follows reach
+            the host, if some live row is certain to need it; else None."""
+            if step > certain:
+                return None
+            self.stats.decode_ahead += 1
+            return dispatch(step, toks, cache, pos)
+
+        def book(host, now) -> int:
+            """Append each live row's token; returns how many rows were live."""
+            live = 0
+            for i, r in enumerate(wave):
+                if done[i]:
+                    continue
+                live += 1
+                outputs[i].append(int(host[i]))
+                if (r.eos_id is not None and host[i] == r.eos_id) or len(outputs[i]) >= r.max_new_tokens:
+                    done[i] = True
+                    r.done_s = now
+            return live
+
         with TraceAnnotation("engine.prefill", wave=n):
             S = max(len(r.prompt) for r in wave)
             prompts = np.zeros((B, S), np.int32)
@@ -102,16 +148,13 @@ class ServingEngine:
                 [r.temperature for r in wave] + [0.0] * (B - len(wave)), jnp.float32
             )
             toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            first = np.asarray(toks)
+            pos = jnp.int32(S)
+            ahead = dispatch_ahead(1, toks, cache, pos)
+            first = np.asarray(toks)[:, 0]
             now = time.perf_counter()
-            outputs = [[] for _ in range(B)]
-            done = np.zeros(B, bool)
-            for i, r in enumerate(wave):
-                outputs[i].append(int(first[i, 0]))
+            for r in wave:
                 r.first_token_s = now
-                if (r.eos_id is not None and first[i, 0] == r.eos_id) or r.max_new_tokens <= 1:
-                    done[i] = True
-                    r.done_s = now
+            book(first, now)
         prompt_tokens = sum(len(r.prompt) for r in wave)
         self.stats.prompt_tokens += prompt_tokens
         self.stats.pad_tokens += B * S - prompt_tokens
@@ -120,24 +163,14 @@ class ServingEngine:
         for step in range(1, max_new):
             if all(done[: len(wave)]):
                 break
-            with TraceAnnotation("engine.dispatch", wave=n, step=step):
-                self._key, sub = jax.random.split(self._key)
-                toks, cache = self._decode(
-                    self.params, toks, cache, jnp.int32(S + step - 1), sub, temps
-                )
+            toks, cache, pos = ahead or dispatch(step, toks, cache, pos)
+            ahead = dispatch_ahead(step + 1, toks, cache, pos)
             with TraceAnnotation("engine.token_sync", wave=n, step=step):
                 host = np.asarray(toks)[:, 0]
             now = time.perf_counter()
             self.stats.decode_steps += 1
             with TraceAnnotation("engine.bookkeeping", wave=n, step=step):
-                for i, r in enumerate(wave):
-                    if done[i]:
-                        continue
-                    outputs[i].append(int(host[i]))
-                    self.stats.decode_rows_live += 1
-                    if (r.eos_id is not None and host[i] == r.eos_id) or len(outputs[i]) >= r.max_new_tokens:
-                        done[i] = True
-                        r.done_s = now
+                self.stats.decode_rows_live += book(host, now)
 
         for i, r in enumerate(wave):
             r.output = np.asarray(outputs[i][: r.max_new_tokens], np.int32)

@@ -64,6 +64,19 @@ def test_engine_spans_nest_in_their_wave(traced):
     assert all(r.first_token_s <= r.done_s for r in reqs)
 
 
+
+def test_next_step_is_dispatched_before_this_steps_tokens_are_read(traced):
+    """In each wave the ``engine.dispatch`` of step k+1 starts before the
+    ``engine.token_sync`` of step k ends: the device holds the next step
+    while the host waits for this one's tokens."""
+    path, _, _ = traced
+    t = trace_reduce.load(path, program_trace.ENGINE_SPANS)
+    for a, b, _ in [w for w in t.spans if w[2] == "engine.wave"]:
+        inside = lambda name: [(s, e) for s, e, n in t.spans if n == name and a <= s and e <= b]
+        dispatch, sync = inside("engine.dispatch"), inside("engine.token_sync")
+        assert len(dispatch) == len(sync) >= 2
+        assert all(d[0] < s[1] for d, s in zip(dispatch[1:], sync))
+
 def _decode_args(engine):
     _, cache = jax.eval_shape(engine._prefill, engine.params, jax.ShapeDtypeStruct((B, 8), jnp.int32))
     return (engine.params, jax.ShapeDtypeStruct((B, 1), jnp.int32), cache,
